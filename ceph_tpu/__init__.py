@@ -20,6 +20,4 @@ Reference parity citations use ``reference:<path>:<line>`` for
 /root/reference (charlewn/ceph).
 """
 
-from . import compat as _compat  # noqa: F401  (asyncio.timeout on 3.10)
-
 __version__ = "0.1.0"

@@ -605,35 +605,38 @@ def _np_hash3(a, b, c):
 
 
 def _np_straw2_rows(NT, x, rows, r):
-    """Exact straw2 per lane-varying bucket: (item, crow, ctype, empty)."""
+    """Exact straw2 per lane-varying bucket: (item, crow, ctype, empty).
+    Column ``i`` is drawn only for the lanes whose bucket has more than
+    ``i`` items (a 16-item host row does not pay for a 64-wide root)."""
     X = len(x)
-    best = None
+    r = np.broadcast_to(r, (X,))
+    best = np.zeros(X, dtype=np.int64)
     bit = np.full(X, _NONE, dtype=np.int64)
     brow = np.full(X, -1, dtype=np.int64)
     btyp = np.zeros(X, dtype=np.int64)
-    I = NT.items.shape[1]
     szs = NT.size[rows]
-    for i in range(I):
-        it = NT.items[rows, i]
-        u = (_np_hash3(x, it & 0xFFFFFFFF, r) & np.uint32(0xFFFF)).astype(
-            np.int64
-        )
-        d = NT.draw_tabs[NT.draw_slot[rows, i], u]
-        d = np.where(i < szs, d, -(1 << 63))  # padding never wins
-        if best is None:
-            best, bit = d, it.copy()
-            brow, btyp = NT.childrow[rows, i], NT.childtype[rows, i]
-        else:
-            better = d > best
-            best = np.where(better, d, best)
-            bit = np.where(better, it, bit)
-            brow = np.where(better, NT.childrow[rows, i], brow)
-            btyp = np.where(better, NT.childtype[rows, i], btyp)
+    for i in range(NT.items.shape[1]):
+        idx = np.nonzero(szs > i)[0]
+        if not idx.size:
+            break  # sizes only shrink past here
+        ri = rows[idx]
+        it = NT.items[ri, i]
+        u = (_np_hash3(x[idx], it & 0xFFFFFFFF, r[idx])
+             & np.uint32(0xFFFF)).astype(np.int64)
+        d = NT.draw_tabs[NT.draw_slot[ri, i], u]
+        if i:  # the first item wins outright, later ones on a larger draw
+            keep = d > best[idx]
+            idx, ri, it, d = idx[keep], ri[keep], it[keep], d[keep]
+        best[idx] = d
+        bit[idx] = it
+        brow[idx] = NT.childrow[ri, i]
+        btyp[idx] = NT.childtype[ri, i]
     return bit, brow, btyp, szs == 0
 
 
 def _np_descend(NT, x, rows0, r, want_type, max_depth):
     X = len(x)
+    r = np.broadcast_to(r, (X,))
     cur = rows0.copy()
     item = np.full(X, _NONE, dtype=np.int64)
     item_row = np.full(X, -1, dtype=np.int64)
@@ -641,17 +644,20 @@ def _np_descend(NT, x, rows0, r, want_type, max_depth):
     dead = np.zeros(X, dtype=bool)
     empty_hit = np.zeros(X, dtype=bool)
     for _d in range(max_depth + 1):
-        it, crow, t, empty = _np_straw2_rows(NT, x, np.maximum(cur, 0), r)
-        live = ~resolved & ~dead & ~empty_hit
-        empty_hit |= live & empty
-        live &= ~empty
+        li = np.nonzero(~resolved & ~dead & ~empty_hit)[0]
+        if not li.size:
+            break
+        it, crow, t, empty = _np_straw2_rows(
+            NT, x[li], np.maximum(cur[li], 0), r[li])
+        empty_hit[li[empty]] = True
+        live = ~empty
         hit = live & (t == want_type)
-        item = np.where(hit, it, item)
-        item_row = np.where(hit, crow, item_row)
-        resolved |= hit
+        item[li[hit]] = it[hit]
+        item_row[li[hit]] = crow[hit]
+        resolved[li[hit]] = True
         godeep = live & ~hit & (it < 0) & (crow >= 0)
-        dead |= live & ~hit & ~godeep
-        cur = np.where(godeep, crow, cur)
+        dead[li[live & ~hit & ~godeep]] = True
+        cur[li[godeep]] = crow[godeep]
     dead |= ~resolved & ~dead & ~empty_hit
     return item, item_row, resolved, dead, empty_hit
 
@@ -681,50 +687,49 @@ def np_choose_firstn_hier(
     numrep, width, tries, recurse_tries, want_type, leaf, vary_r, stable,
     max_depth,
 ):
-    """Host-exact mirror of choose_firstn_hier (same masked control flow,
-    table-exact draws)."""
+    """Host-exact mirror of choose_firstn_hier (same control flow,
+    table-exact draws); each try runs only the lanes still choosing."""
     X = len(x)
     out = np.full((X, width), _NONE, dtype=np.int64)
     out2 = np.full((X, width), _NONE, dtype=np.int64)
     outpos = np.zeros(X, dtype=np.int64)
-    roots = np.full(X, root_row, dtype=np.int64)
+    roots = np.broadcast_to(np.asarray(root_row, dtype=np.int64), (X,))
     for rep in range(numrep):
         active = outpos < width
         ftotal = np.zeros(X, dtype=np.int64)
         while True:
-            live = active & (ftotal < tries)
-            if not live.any():
+            li = np.nonzero(active & (ftotal < tries))[0]
+            if not li.size:
                 break
-            r = rep + ftotal
+            xl, pos = x[li], outpos[li]
+            r = rep + ftotal[li]
             item, item_row, resolved, dead, empty = _np_descend(
-                NT, x, roots, r, want_type, max_depth
+                NT, xl, roots[li], r, want_type, max_depth
             )
-            coll = _np_collides(out, outpos, item)
+            coll = _np_collides(out[li], pos, item)
             if leaf:
                 sub_r = (r >> (vary_r - 1)) if vary_r else np.zeros_like(r)
-                rep2 = np.zeros_like(outpos) if stable else outpos
-                want_leaf = live & resolved & ~coll
+                rep2 = np.zeros_like(pos) if stable else pos
+                want_leaf = resolved & ~coll
                 leaf_item, leaf_ok = _np_leaf_firstn(
-                    NT, x, item_row, rep2, sub_r, out2, outpos, weight,
+                    NT, xl, item_row, rep2, sub_r, out2[li], pos, weight,
                     recurse_tries, max_depth, want_leaf,
                 )
                 rej_leaf = want_leaf & ~leaf_ok
             else:
                 leaf_item = item
-                rej_leaf = np.zeros_like(live)
+                rej_leaf = np.zeros(len(li), dtype=bool)
             if want_type == 0 and not leaf:
-                rej_out = resolved & ~coll & _np_is_out(x, weight, item)
+                rej_out = resolved & ~coll & _np_is_out(xl, weight, item)
             else:
-                rej_out = np.zeros_like(live)
-            reject = empty | rej_leaf | rej_out
-            ok = live & resolved & ~coll & ~reject
-            slot = np.minimum(outpos, width - 1)
-            lanes = np.arange(X)
-            out[lanes[ok], slot[ok]] = item[ok]
-            out2[lanes[ok], slot[ok]] = (leaf_item if leaf else item)[ok]
-            outpos += ok.astype(np.int64)
-            active &= ~ok & ~(live & dead)
-            ftotal += (live & ~ok & ~dead).astype(np.int64)
+                rej_out = np.zeros(len(li), dtype=bool)
+            ok = resolved & ~coll & ~(empty | rej_leaf | rej_out)
+            slot = np.minimum(pos, width - 1)
+            out[li[ok], slot[ok]] = item[ok]
+            out2[li[ok], slot[ok]] = leaf_item[ok]
+            outpos[li] += ok
+            active[li] &= ~ok & ~dead
+            ftotal[li] += ~ok & ~dead
     return out, out2
 
 
@@ -738,20 +743,20 @@ def _np_leaf_firstn(
     failed = np.zeros(X, dtype=bool)
     ftotal = np.zeros(X, dtype=np.int64)
     for _t in range(recurse_tries):
-        live = want & ~done & ~failed & (ftotal < recurse_tries)
-        if not live.any():
+        li = np.nonzero(want & ~done & ~failed
+                        & (ftotal < recurse_tries))[0]
+        if not li.size:
             break
-        r2 = rep2 + sub_r + ftotal
         item, _row, resolved, dead, empty = _np_descend(
-            NT, x, np.maximum(sub_rows, 0), r2, 0, max_depth
+            NT, x[li], np.maximum(sub_rows[li], 0),
+            rep2[li] + sub_r[li] + ftotal[li], 0, max_depth
         )
-        coll = _np_collides(out2, outpos, item)
-        rej = resolved & (coll | _np_is_out(x, weight, item))
-        ok_now = live & resolved & ~rej
-        leaf = np.where(ok_now, item, leaf)
-        done |= ok_now
-        failed |= live & dead
-        ftotal += (live & ~ok_now & ~dead).astype(np.int64)
+        coll = _np_collides(out2[li], outpos[li], item)
+        ok_now = resolved & ~(coll | _np_is_out(x[li], weight, item))
+        leaf[li[ok_now]] = item[ok_now]
+        done[li[ok_now]] = True
+        failed[li[dead]] = True
+        ftotal[li] += ~ok_now & ~dead
     return leaf, done
 
 
@@ -759,49 +764,45 @@ def np_choose_indep_hier(
     NT, x, root_row, weight,
     numrep, out_size, tries, recurse_tries, want_type, leaf, max_depth,
 ):
-    """Host-exact mirror of choose_indep_hier."""
+    """Host-exact mirror of choose_indep_hier; each (try, slot) runs
+    only the lanes whose slot is still undecided."""
     X = len(x)
     out = np.full((X, out_size), _UNDEF, dtype=np.int64)
     out2 = np.full((X, out_size), _UNDEF, dtype=np.int64)
     # scalar root (one TAKE bucket) or per-lane roots (chained steps)
-    roots = np.broadcast_to(
-        np.asarray(root_row, dtype=np.int64), (X,)
-    ).copy()
+    roots = np.broadcast_to(np.asarray(root_row, dtype=np.int64), (X,))
     for ftotal in range(tries):
         if not (out == _UNDEF).any():
             break
         for rep in range(out_size):
-            need = out[:, rep] == _UNDEF
-            if not need.any():
+            li = np.nonzero(out[:, rep] == _UNDEF)[0]
+            if not li.size:
                 continue
-            r = np.full(X, rep + numrep * ftotal, dtype=np.int64)
+            xl = x[li]
+            r = np.full(len(li), rep + numrep * ftotal, dtype=np.int64)
             item, item_row, resolved, dead, empty = _np_descend(
-                NT, x, roots, r, want_type, max_depth
+                NT, xl, roots[li], r, want_type, max_depth
             )
-            perm = need & dead
-            coll = (out == item[:, None]).any(axis=1)
+            coll = (out[li] == item[:, None]).any(axis=1)
             if leaf:
-                want_leaf = need & resolved & ~coll
+                want_leaf = resolved & ~coll
                 leaf_item, leaf_ok = _np_leaf_indep(
-                    NT, x, item_row, rep, r, weight,
+                    NT, xl, item_row, rep, r, weight,
                     numrep, recurse_tries, max_depth, want_leaf,
                 )
                 rej_leaf = want_leaf & ~leaf_ok
             else:
                 leaf_item = item
-                rej_leaf = np.zeros_like(need)
+                rej_leaf = np.zeros(len(li), dtype=bool)
             if want_type == 0 and not leaf:
-                rej_out = resolved & ~coll & _np_is_out(x, weight, item)
+                rej_out = resolved & ~coll & _np_is_out(xl, weight, item)
             else:
-                rej_out = np.zeros_like(need)
-            ok = need & resolved & ~coll & ~rej_leaf & ~rej_out & ~perm
-            out[:, rep] = np.where(
-                ok, item, np.where(perm, _NONE, out[:, rep])
-            )
-            out2[:, rep] = np.where(
-                ok, (leaf_item if leaf else item),
-                np.where(perm, _NONE, out2[:, rep]),
-            )
+                rej_out = np.zeros(len(li), dtype=bool)
+            ok = resolved & ~coll & ~rej_leaf & ~rej_out & ~dead
+            out[li[ok], rep] = item[ok]
+            out2[li[ok], rep] = leaf_item[ok]
+            out[li[dead], rep] = _NONE
+            out2[li[dead], rep] = _NONE
     out = np.where(out == _UNDEF, _NONE, out)
     out2 = np.where(out2 == _UNDEF, _NONE, out2)
     return out, out2
@@ -812,22 +813,22 @@ def _np_leaf_indep(
     numrep, recurse_tries, max_depth, want,
 ):
     X = len(x)
+    parent_r = np.broadcast_to(parent_r, (X,))
     leaf = np.full(X, _NONE, dtype=np.int64)
     done = np.zeros(X, dtype=bool)
     deadf = np.zeros(X, dtype=bool)
     for ft2 in range(recurse_tries):
-        live = want & ~done & ~deadf
-        if not live.any():
+        li = np.nonzero(want & ~done & ~deadf)[0]
+        if not li.size:
             break
-        r2 = rep + parent_r + numrep * ft2
         item, _row, resolved, dead, empty = _np_descend(
-            NT, x, np.maximum(sub_rows, 0), r2, 0, max_depth
+            NT, x[li], np.maximum(sub_rows[li], 0),
+            rep + parent_r[li] + numrep * ft2, 0, max_depth
         )
-        rej = resolved & _np_is_out(x, weight, item)
-        ok_now = live & resolved & ~rej
-        leaf = np.where(ok_now, item, leaf)
-        done |= ok_now
-        deadf |= live & dead
+        ok_now = resolved & ~_np_is_out(x[li], weight, item)
+        leaf[li[ok_now]] = item[ok_now]
+        done[li[ok_now]] = True
+        deadf[li[dead]] = True
     return leaf, done
 
 

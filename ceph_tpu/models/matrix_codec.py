@@ -36,8 +36,6 @@ from ..ops.gf_jax import (
     make_bitmatrix_matmul_u32_routed,
     make_gf_matmul,
     make_gf_matmul_u32_routed,
-    make_xor_parity,
-    make_xor_parity_u32,
     u32_to_bytes,
 )
 from ..ops.profiler import profiler
@@ -58,10 +56,7 @@ def _donation_enabled() -> bool:
     env = os.environ.get("CEPH_TPU_EC_DONATE")
     if env is not None:
         return env == "1"
-    try:
-        return jax.default_backend() != "cpu"
-    except Exception:
-        return False
+    return jax.default_backend() != "cpu"
 
 
 def _maybe_jit(fn, donate_argnums=()):
@@ -77,10 +72,7 @@ def _maybe_jit(fn, donate_argnums=()):
 
 @functools.lru_cache(maxsize=512)
 def _jit_matmul(matrix_key: tuple, w: int):
-    matrix = np.array(matrix_key, dtype=np.int64)
-    if matrix.shape[0] == 1 and np.all(matrix == 1):
-        return _maybe_jit(make_xor_parity())
-    return _maybe_jit(make_gf_matmul(matrix, w))
+    return _maybe_jit(make_gf_matmul(np.array(matrix_key, dtype=np.int64), w))
 
 
 @functools.lru_cache(maxsize=512)
@@ -89,8 +81,6 @@ def _jit_matmul_u32(matrix_key: tuple, w: int):
     device-side uint8<->u32 relayout per call — callers reinterpret on
     the host for free with bytes_to_u32/u32_to_bytes)."""
     matrix = np.array(matrix_key, dtype=np.int64)
-    if matrix.shape[0] == 1 and np.all(matrix == 1):
-        return _maybe_jit(make_xor_parity_u32(), donate_argnums=(0,))
     return _maybe_jit(make_gf_matmul_u32_routed(matrix, w),
                       donate_argnums=(0,))
 
@@ -105,11 +95,7 @@ def _jit_encode_shards_u32(matrix_key: tuple, w: int):
     matmul, and concatenates data+parity rows — XLA fuses the transpose
     into the kernel reads, and the caller materializes ONE [k+m, S*C4]
     result whose rows are the per-shard buffers."""
-    matrix = np.array(matrix_key, dtype=np.int64)
-    if matrix.shape[0] == 1 and np.all(matrix == 1):
-        inner = make_xor_parity_u32()
-    else:
-        inner = make_gf_matmul_u32_routed(matrix, w)
+    inner = make_gf_matmul_u32_routed(np.array(matrix_key, dtype=np.int64), w)
 
     def fn(d3):  # [S, k, C4] u32
         S, k, C4 = d3.shape

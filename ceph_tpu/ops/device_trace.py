@@ -60,17 +60,22 @@ from typing import Any, Hashable, Iterable
 BUCKETS = ("fused_op", "dma", "collective")
 
 # ICI/NCCL-collective HLO names (all-gather.1, all-reduce-start,
-# reduce-scatter.3, collective-permute...).  Hyphenated forms only:
-# "reduce-window" / "reduce.8" are plain compute and must NOT match.
-_COLLECTIVE_MARKS = (
-    "all-gather", "all-reduce", "allgather", "allreduce",
-    "reduce-scatter", "all-to-all", "collective-permute",
-    "collective-broadcast", "ragged-all-to-all",
-)
+# reduce-scatter.3, collective-permute...), hyphenated and in the
+# underscore spelling the installed jax's profiler writes
+# (all_gather.3).  "reduce-window" / "reduce.8" are plain compute and
+# must NOT match.
+_COLLECTIVE_MARKS = tuple(
+    spelling
+    for mark in ("all-gather", "all-reduce", "reduce-scatter",
+                 "all-to-all", "collective-permute",
+                 "collective-broadcast", "ragged-all-to-all")
+    for spelling in (mark, mark.replace("-", "_"))
+) + ("allgather", "allreduce")
 # DMA / host<->device transfer names: HLO copy ops, infeed/outfeed,
 # TPU DMA rows, PJRT transfer events
 _DMA_MARKS = (
     "infeed", "outfeed", "dma", "memcpy", "copy-start", "copy-done",
+    "copy_start", "copy_done",
     "host-to-device", "device-to-host", "h2d", "d2h", "transferto",
     "transferfrom", "buffertransfer",
 )
@@ -93,7 +98,10 @@ def classify_trace_event(name: str, args: dict | None = None,
     if low.startswith("$"):
         return None  # python stack frames the profiler interleaves
     collective = any(m in low for m in _COLLECTIVE_MARKS)
-    if hlo:
+    # a TPU device's "XLA Ops" row holds exactly one event per HLO op,
+    # whatever args this profiler version attaches ("XLA Modules" spans
+    # wrap them and stay uncounted)
+    if hlo or tlow == "xla ops":
         # HLO send/recv ARE cross-chip transfers; a host runtime event
         # merely containing "send" (MessageSend...) must not be
         if collective or low.startswith(("send", "recv")):

@@ -34,11 +34,10 @@ from .gf_jax import _PACK, _row_plans
 BLOCK = 4096  # u32 lanes per grid step (x4 = 16 KiB per row)
 
 
-def _have_pallas_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU.  A backend that fails
+    to start raises here: it is never read as "no TPU"."""
+    return jax.default_backend() == "tpu"
 
 
 def make_gf_matmul_pallas(matrix: np.ndarray, w: int = 8,

@@ -4,7 +4,7 @@ vectorized CRUSH mapper.
 The hot path the paper cares about — GF(2^8) encode/decode behind
 ``ErasureCodePluginTPU`` and ``crush.mapper_jax`` — previously had zero
 internal visibility: a bench run dying inside backend acquisition left
-no phase breakdown at all (BENCH_r01..r05).  This module is the
+no phase breakdown at all.  This module is the
 process-global timing tap every host-side kernel entry reports into:
 
 - **trace/compile vs execute split**: jitted callables compile once per

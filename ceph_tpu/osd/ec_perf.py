@@ -45,8 +45,8 @@ def create_ec_perf(perf):
     pec.add_gauge("mesh_devices",
                   "devices in the EC mesh slice (pg x shard) as "
                   "seen by the last mesh-lane launch")
-    # per-engine codec throughput (the number bench.py and
-    # TPU_EVIDENCE track): last-call GB/s gauges + wall-time avgs
+    # per-engine codec throughput (the number bench.py tracks):
+    # last-call GB/s gauges + wall-time avgs
     pec.add_gauge("encode_gbps", "host-path encode GB/s (last call)")
     pec.add_gauge("decode_gbps", "host-path decode GB/s (last call)")
     pec.add_gauge("mesh_encode_gbps",

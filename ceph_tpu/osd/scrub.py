@@ -9,7 +9,7 @@ re-pushing authoritative replicas on replicated pools
 (reference:src/osd/ECBackend.cc:2313 be_deep_scrub;
 reference:src/osd/PrimaryLogPG.cc scrub repair flow).
 
-Error classes (the reference's scrub-error taxonomy, narrowed):
+Error classes (the reference's scrub-error classes, narrowed):
 - ``missing``: a shard/replica the acting set should hold is absent
 - ``crc``: stored bytes do not match the shard's own crc table (bitrot)
 - ``stale``: a shard holds an older version than its peers

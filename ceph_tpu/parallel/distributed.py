@@ -81,18 +81,17 @@ def make_ec_step(
         g = jax.lax.all_gather(surv, "shard", axis=1, tiled=True)  # [S/pg, k, C]
         return _flat(dec, g)
 
-    from .mesh import shard_map_compat
+    from .mesh import shard_map
 
-    shard_encode = shard_map_compat(
+    shard_encode = shard_map(
         local_encode, mesh,
         in_specs=P("pg", None, None), out_specs=P("pg", None, None),
     )
     # after the all_gather every 'shard' member computes the same rebuilt
     # rows (replicated output) — the static replication check can't see it
-    shard_reconstruct = shard_map_compat(
+    shard_reconstruct = shard_map(
         local_reconstruct, mesh,
         in_specs=P("pg", "shard", None), out_specs=P("pg", None, None),
-        replicated_ok=True,
     )
 
     present_idx = jnp.array(present[:k])

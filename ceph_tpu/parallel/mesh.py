@@ -9,30 +9,14 @@ import numpy as np
 from jax.sharding import Mesh
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs,
-                     replicated_ok: bool = False):
-    """Version-portable ``shard_map``: new jax exposes ``jax.shard_map``
-    (replication opt-out spelled ``check_vma=False``), 0.4.x ships it
-    as ``jax.experimental.shard_map.shard_map`` (``check_rep=False``).
-    ``replicated_ok=True`` disables the static replication check — the
-    reconstruct programs produce outputs replicated over the gather
-    axis, which the checker cannot see through an all_gather."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            if replicated_ok:
-                return sm(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs)
-        # swallow-ok: kwargs-spelling probe — this jax wants the 0.4.x keywords, fall through to the experimental entry (nothing launched yet)
-        except TypeError:
-            pass
-    from jax.experimental.shard_map import shard_map as esm
-
-    kw = {"check_rep": False} if replicated_ok else {}
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               **kw)
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` without the static varying-axes check: the
+    Pallas kernels' outputs carry no varying-axes annotation (with the
+    check on, a mesh program that runs one fails to trace for a TPU),
+    and the reconstruct programs' outputs are replicated over the
+    gather axis, which the checker cannot see through an all_gather."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def ec_shard_axis(k: int, n_devices: int) -> int:

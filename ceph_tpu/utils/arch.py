@@ -16,8 +16,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import os
+import pathlib
 import platform as _host_platform
 import subprocess
+
+_REPO = pathlib.Path(__file__).resolve().parents[2]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,20 +54,35 @@ def probe() -> ArchProbe:
     """
     import jax
 
-    try:
-        devices = jax.devices()
-        plat = devices[0].platform
-        kind = devices[0].device_kind
-        n = len(devices)
-    except Exception:  # backend init failed — host-only mode
-        plat, kind, n = "cpu", "unknown", 0
+    # a backend that fails to start raises: it is never reported as a
+    # CPU host, so the caller sees the fault instead of a host number
+    devices = jax.devices()
+    plat = devices[0].platform
     return ArchProbe(
         platform=plat,
-        device_kind=kind,
-        num_devices=n,
+        device_kind=devices[0].device_kind,
+        num_devices=len(devices),
         has_mxu=plat == "tpu",
         host_machine=_host_platform.machine(),
     )
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    Called by the entry points that hold a device (chip_smoke.py,
+    bench.py's device process, the ``accel`` daemon role).  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and
+    nothing is set here; otherwise the cache is the fixed
+    ``<repo>/.jax_cache`` (a fixed path, so a later run finds it)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = str(_REPO / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 @functools.lru_cache(maxsize=None)

@@ -1,6 +1,9 @@
 """ctypes loader + wrapper for the native C++ engine (native/ec_cpu.cc).
 
-Builds on first use (g++ -O3 -march=native) into native/build/.  This is
+Builds on first use (g++ -O3 -march=native) into native/build/, under a
+name keyed on the source, the command and the CPU the compiler targets
+(:func:`build_so`), so a copied tree never loads a binary built for
+another host.  This is
 the host-side codec used as the CPU baseline in bench.py and as an
 independent oracle for the TPU kernels (both implement the same doubling
 scheme, so parity bytes must agree exactly with each other and with the
@@ -10,6 +13,9 @@ numpy table-based oracle).
 from __future__ import annotations
 
 import ctypes
+import functools
+import hashlib
+import os
 import pathlib
 import subprocess
 import threading
@@ -19,26 +25,52 @@ import numpy as np
 _ROOT = pathlib.Path(__file__).resolve().parent.parent.parent
 _SRC = _ROOT / "native" / "ec_cpu.cc"
 _BUILD = _ROOT / "native" / "build"
-_SO = _BUILD / "libec_cpu.so"
 
 _lock = threading.Lock()
 _lib = None
 
 
-def build(force: bool = False) -> pathlib.Path:
-    """Compile the native library if needed; returns the .so path."""
-    if _SO.exists() and not force:
-        if _SO.stat().st_mtime >= _SRC.stat().st_mtime:
-            return _SO
+@functools.lru_cache(maxsize=None)
+def _compiler_target(flags: tuple[str, ...]) -> str:
+    """The compiler's own expansion of ``flags``: ``-march=native``
+    becomes this host's CPU and its ISA extensions there."""
+    r = subprocess.run(
+        ["g++", *flags, "-E", "-v", "-x", "c++", os.devnull,
+         "-o", os.devnull],
+        capture_output=True, text=True, check=True,
+    )
+    return "\n".join(ln for ln in r.stderr.splitlines() if "cc1plus" in ln)
+
+
+def build_so(stem: str, sources: list[pathlib.Path],
+             cmd: list[str]) -> pathlib.Path:
+    """Compile ``cmd`` into ``native/build/<stem>-<key>.so`` unless that
+    file exists.  The key hashes the sources, the command and the
+    compiler's expansion of it, never a file time."""
+    h = hashlib.sha256()
+    for src in sources:
+        h.update(src.read_bytes())
+    h.update("\0".join(cmd).encode())
+    h.update(_compiler_target(tuple(c for c in cmd if c.startswith("-m")))
+             .encode())
+    so = _BUILD / f"{stem}-{h.hexdigest()[:16]}.so"
+    if so.exists():
+        return so
     _BUILD.mkdir(parents=True, exist_ok=True)
+    tmp = so.with_suffix(f".{os.getpid()}.tmp")
+    subprocess.run([*cmd, "-o", str(tmp)], check=True, capture_output=True)
+    os.replace(tmp, so)  # atomic: a concurrent builder loads a whole file
+    return so
+
+
+def build() -> pathlib.Path:
+    """Compile the native library if needed; returns the .so path."""
     from .arch import host_march_flags
 
-    cmd = [
+    return build_so("libec_cpu", [_SRC], [
         "g++", "-O3", *host_march_flags(), "-funroll-loops", "-shared",
-        "-fPIC", "-std=c++17", str(_SRC), "-o", str(_SO),
-    ]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return _SO
+        "-fPIC", "-std=c++17", str(_SRC),
+    ])
 
 
 def lib() -> ctypes.CDLL:
@@ -113,18 +145,20 @@ _HOST_ACTIVE: bool | None = None
 def host_engine_active() -> bool:
     """True when jax's default backend is the host CPU and this native
     GF engine is loadable — the ONE routing gate shared by the encode
-    stack (osd/ec_util) and the codec decode path (models/matrix_codec);
-    code review r5: two divergent copies of this policy disagreed on
-    failure defaults."""
+    stack (osd/ec_util) and the codec decode path (models/matrix_codec).
+    A backend that fails to start raises here; only a native library
+    that cannot be built reads as "not active"."""
     global _HOST_ACTIVE
     if _HOST_ACTIVE is None:
-        try:
-            import jax
+        import jax
 
-            lib()
-            _HOST_ACTIVE = jax.default_backend() == "cpu"
-        except Exception:
-            _HOST_ACTIVE = False
+        active = jax.default_backend() == "cpu"
+        if active:
+            try:
+                lib()
+            except (OSError, subprocess.CalledProcessError):
+                active = False  # no compiler here: the jax lane serves
+        _HOST_ACTIVE = active
     return _HOST_ACTIVE
 
 

@@ -11,8 +11,8 @@ share one source of truth.
 from __future__ import annotations
 
 import ctypes
+import os
 import pathlib
-import subprocess
 import threading
 import time
 
@@ -21,7 +21,6 @@ import numpy as np
 _ROOT = pathlib.Path(__file__).resolve().parent.parent.parent
 _SRC = _ROOT / "native" / "crush_cpu.cc"
 _BUILD = _ROOT / "native" / "build"
-_SO = _BUILD / "libcrush_cpu.so"
 _INC = _BUILD / "crush_ln_tables.inc"
 
 _lock = threading.Lock()
@@ -40,26 +39,28 @@ def _write_tables() -> None:
             f"static const uint64_t {name}[{len(vals)}] = {{\n  {body}\n}};\n"
         )
 
-    _INC.write_text(
+    text = (
         "// GENERATED from ceph_tpu/crush/ln_tables.py — do not edit\n"
         + fmt("RH_LH_TBL", RH_LH_TBL)
         + fmt("LL_TBL", LL_TBL)
     )
-
-
-def build(force: bool = False) -> pathlib.Path:
-    if _SO.exists() and not force and _SO.stat().st_mtime >= _SRC.stat().st_mtime:
-        return _SO
+    if _INC.exists() and _INC.read_text() == text:
+        return
     _BUILD.mkdir(parents=True, exist_ok=True)
-    _write_tables()
-    from .arch import host_march_flags
+    tmp = _INC.with_suffix(f".{os.getpid()}.tmp")
+    tmp.write_text(text)
+    os.replace(tmp, _INC)
 
-    cmd = [
+
+def build() -> pathlib.Path:
+    from .arch import host_march_flags
+    from .native import build_so
+
+    _write_tables()
+    return build_so("libcrush_cpu", [_SRC, _INC], [
         "g++", "-O3", *host_march_flags(), "-funroll-loops", "-shared",
-        "-fPIC", "-std=c++17", f"-I{_BUILD}", str(_SRC), "-o", str(_SO),
-    ]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return _SO
+        "-fPIC", "-std=c++17", f"-I{_BUILD}", str(_SRC),
+    ])
 
 
 def lib() -> ctypes.CDLL:

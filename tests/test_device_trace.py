@@ -91,7 +91,18 @@ class TestClassify:
         assert classify_trace_event("0xaf 128KiB", None,
                                     "DMA transfers") == "dma"
         assert classify_trace_event("anything", None, "Infeed") == "dma"
-        assert classify_trace_event("anything", None, "XLA Ops") is None
+
+    def test_tpu_op_rows(self):
+        """A TPU device's "XLA Ops" row is one event per HLO op, with or
+        without hlo args; the "XLA Modules" spans that wrap them are not
+        counted again."""
+        assert classify_trace_event("fusion.7", None, "XLA Ops") \
+            == "fused_op"
+        assert classify_trace_event("all-gather.2", {}, "XLA Ops") \
+            == "collective"
+        assert classify_trace_event("copy.1", None, "XLA Ops") == "dma"
+        assert classify_trace_event("jit_fn(123)", None,
+                                    "XLA Modules") is None
 
 
 class TestFixture:

@@ -14,6 +14,24 @@ def test_native_builds():
     assert native.build().exists()
 
 
+def test_build_is_keyed_on_source_and_flags(tmp_path, monkeypatch):
+    """The .so name hashes the source, the command and the CPU the
+    compiler targets: another source or flag set builds another file,
+    the same inputs reuse theirs, and no file time is read."""
+    import re
+
+    monkeypatch.setattr(native, "_BUILD", tmp_path)
+    src = tmp_path / "one.cc"
+    src.write_text("extern \"C\" int one() { return 1; }\n")
+    cmd = ["g++", "-O1", "-shared", "-fPIC", str(src)]
+    so = native.build_so("libone", [src], cmd)
+    assert re.fullmatch(r"libone-[0-9a-f]{16}\.so", so.name)
+    assert native.build_so("libone", [src], cmd) == so
+    assert native.build_so("libone", [src], [*cmd, "-O2"]) != so
+    src.write_text("extern \"C\" int one() { return 2; }\n")
+    assert native.build_so("libone", [src], cmd) != so
+
+
 def test_mul_region_matches():
     G = gf(8)
     region = RNG.integers(0, 256, size=4096).astype(np.uint8)

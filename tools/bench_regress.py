@@ -7,16 +7,15 @@ Each round's driver writes ``BENCH_r<N>.json`` with the shape
 final JSON line (or null when the run produced none); bare final-line
 JSON files are accepted too.  This tool loads the last N rounds,
 compares the newest measurement against the best earlier one **with
-the same phase** — a "native-only" round after a "tpu" round is an
-environment fault, not a kernel regression, and must not trip the gate
-(nor silently pass a real TPU slowdown by averaging apples with
-oranges) — and exits nonzero when the newest throughput falls below
-``threshold`` x the prior best.
+the same phase** — the committed pre-PR-21 rounds include
+"native-only" host fallbacks, which are not TPU measurements (bench.py
+no longer produces them: a run with no chip prints a null value) — and
+exits nonzero when the newest throughput falls below ``threshold`` x
+the prior best.
 
-Same-phase is necessary but not sufficient: the jax-cpu fallback
-shrinks its batch to 8 MiB under tight budgets while TPU rounds run
-the full 64 MiB, and GB/s at 8 MiB is not GB/s at 64 MiB (less launch
-amortization).  Rounds now record ``batch_bytes`` in the final line;
+Same-phase is necessary but not sufficient: a ``--batch 8`` run moves
+8 MiB per launch where the default moves 64 MiB, and GB/s at 8 MiB is
+not GB/s at 64 MiB (less launch amortization).  Rounds now record ``batch_bytes`` in the final line;
 when both the newest round and a prior record it, a mismatch excludes
 that prior from the comparison (listed in the report as
 ``excluded_batch_mismatch``).  Rounds predating the field are compared
@@ -28,11 +27,11 @@ starvation-gate protection factor (fifo p99 / mclock p99 — how much
 tail latency the dmClock scheduler buys under a recovery storm, higher
 is better, same direction as every throughput metric here).
 
-``--metric stack_gbps`` is first-class: the codec-stack measurement is
-taken on the cpu backend EVERY round (bench.py runs it serially,
-whatever the TPU does), so unlike the headline it is comparable across
-phase flips — a "native-only" fallback round still measured the same
-stack.  Metrics in ``PHASE_AGNOSTIC_METRICS`` therefore skip the
+``--metric stack_gbps`` is first-class: before PR 21 the codec-stack
+measurement was taken on the cpu backend every round, so unlike the
+headline it was comparable across phase flips — a "native-only"
+fallback round still measured the same stack (bench.py now takes it in
+the device child).  Metrics in ``PHASE_AGNOSTIC_METRICS`` therefore skip the
 same-phase filter (and the batch_bytes filter, which only qualifies
 the headline's device batches).  This is the zero-copy data path's
 monotonic gate: once the stack gap closes, a PR that re-introduces
