@@ -285,7 +285,13 @@ class _FrameChannel(asyncio.BufferedProtocol):
             self.transport.write(data)
 
     def writelines(self, segs) -> None:
-        if not self._conn_lost:
+        # a closing transport takes no more bytes: CPython 3.12's
+        # writelines() does not check, and on an aborted or closed
+        # transport it registers a writer for a socket whose close is
+        # already queued.  The selector keeps that stale entry after
+        # the close, so the next socket to reuse the fd number fails
+        # to register for reading and never hears from its peer.
+        if not self._conn_lost and not self.transport.is_closing():
             self.transport.writelines(segs)
 
     async def drain(self) -> None:
@@ -695,8 +701,12 @@ class AsyncMessenger:
         self.op_batch_max = 16
         self._server: asyncio.AbstractServer | None = None
         self._conns: dict[str, Connection] = {}  # outbound, keyed by peer addr
-        self._pending: dict[str, asyncio.Future] = {}  # in-flight connects
+        self._pending: dict[str, asyncio.Task] = {}  # in-flight dials
         self._all: set[Connection] = set()
+        # inbound channels still in the banner/auth handshake: not yet
+        # in _all, but their transports keep the server's wait_closed()
+        # waiting, so shutdown() closes them too
+        self._handshaking: set[_FrameChannel] = set()
         self._stopped = False
         # CephX-style handshake auth (reference AuthAuthorizer in the
         # messenger handshake): when set, outbound banners carry the
@@ -800,6 +810,10 @@ class AsyncMessenger:
         self._stopped = True
         if self._server is not None:
             self._server.close()
+        for dial in list(self._pending.values()):
+            dial.cancel()
+        for ch in list(self._handshaking):
+            ch.close()
         conns = list(self._all)
         for conn in conns:
             await conn.close()
@@ -828,58 +842,20 @@ class AsyncMessenger:
             ch.close()
             return
         conn = Connection(self, ch)
+        self._handshaking.add(ch)
         try:
-            banner = json.loads(  # wire-ok: banner handshake, line-based
-                (await ch.readline()).decode())
-            conn.peer_name = banner["entity"]
-            conn.peer_addr = banner.get("addr", "")
-            if self.auth is not None and self.auth.require:
-                # the TICKET's entity is the authenticated identity; the
-                # banner name is just the instance label (many clients
-                # share one keyring entity, like client.admin)
-                entity = None
-                if banner.get("authorizer") is not None:
-                    # challenge-bound verification: the peer must prove it
-                    # holds the ticket's session key, not just ticket
-                    # bytes observable from an earlier handshake (the
-                    # reference's authorizer challenge, CVE-2018-1128)
-                    from ..auth import new_secret
-
-                    nonce = new_secret()
-                    ch.write(  # wire-ok: auth challenge, handshake line
-                        json.dumps({"challenge": nonce}).encode() + b"\n"
-                    )
-                    await ch.drain()
-                    answer = json.loads(  # wire-ok: auth proof, handshake line
-                        (await ch.readline()).decode())
-                    if not isinstance(answer, dict):
-                        answer = {}
-                    entity = self.auth.verify(
-                        banner["authorizer"],
-                        challenge=nonce,
-                        proof=answer.get("proof"),
-                    )
-                conn.auth_entity = entity or ""
-                if entity is None:
-                    if self.auth_mon_mode:
-                        # the mon admits the conn but only for the MAuth
-                        # exchange (the CephX bootstrap); the dispatcher
-                        # gates everything else on conn.authenticated
-                        conn.authenticated = False
-                    else:
-                        ch.write(  # wire-ok: auth rejection, handshake line
-                            json.dumps({"error": "auth failed"}).encode()
-                            + b"\n"
-                        )
-                        await ch.drain()
-                        ch.close()
-                        return
-            ch.write(  # wire-ok: banner handshake, line-based
-                json.dumps({"entity": self.name, "addr": self.addr}).encode() + b"\n"
-            )
-            await ch.drain()
-        # swallow-ok: malformed/failed handshake — closing the conn is the reply
-        except (ValueError, KeyError, TypeError, ConnectionError, OSError):
+            # a peer that connects and then says nothing must not hold
+            # the channel open for ever: the inbound handshake has the
+            # outbound one's bound
+            async with asyncio.timeout(self.connect_timeout):
+                admitted = await self._handshake_inbound(ch, conn)
+        # swallow-ok: malformed/failed/stalled handshake — closing the conn is the reply
+        except (ValueError, KeyError, TypeError, ConnectionError, OSError,
+                TimeoutError):
+            admitted = False
+        finally:
+            self._handshaking.discard(ch)
+        if not admitted:
             ch.close()
             return
         # handshake done: everything after the dialer's last line is
@@ -889,29 +865,92 @@ class AsyncMessenger:
         self.perf.inc("conns_accepted")
         self._start(conn)
 
+    async def _handshake_inbound(self, ch: _FrameChannel,
+                                 conn: Connection) -> bool:
+        """The acceptor's half of the banner (and auth) exchange;
+        False when the peer is refused."""
+        banner = json.loads(  # wire-ok: banner handshake, line-based
+            (await ch.readline()).decode())
+        conn.peer_name = banner["entity"]
+        conn.peer_addr = banner.get("addr", "")
+        if self.auth is not None and self.auth.require:
+            # the TICKET's entity is the authenticated identity; the
+            # banner name is just the instance label (many clients
+            # share one keyring entity, like client.admin)
+            entity = None
+            if banner.get("authorizer") is not None:
+                # challenge-bound verification: the peer must prove it
+                # holds the ticket's session key, not just ticket
+                # bytes observable from an earlier handshake (the
+                # reference's authorizer challenge, CVE-2018-1128)
+                from ..auth import new_secret
+
+                nonce = new_secret()
+                ch.write(  # wire-ok: auth challenge, handshake line
+                    json.dumps({"challenge": nonce}).encode() + b"\n"
+                )
+                await ch.drain()
+                answer = json.loads(  # wire-ok: auth proof, handshake line
+                    (await ch.readline()).decode())
+                if not isinstance(answer, dict):
+                    answer = {}
+                entity = self.auth.verify(
+                    banner["authorizer"],
+                    challenge=nonce,
+                    proof=answer.get("proof"),
+                )
+            conn.auth_entity = entity or ""
+            if entity is None:
+                if self.auth_mon_mode:
+                    # the mon admits the conn but only for the MAuth
+                    # exchange (the CephX bootstrap); the dispatcher
+                    # gates everything else on conn.authenticated
+                    conn.authenticated = False
+                else:
+                    ch.write(  # wire-ok: auth rejection, handshake line
+                        json.dumps({"error": "auth failed"}).encode()
+                        + b"\n"
+                    )
+                    await ch.drain()
+                    return False
+        ch.write(  # wire-ok: banner handshake, line-based
+            json.dumps({"entity": self.name, "addr": self.addr}).encode() + b"\n"
+        )
+        await ch.drain()
+        return True
+
     async def connect(self, addr: str, peer_name: str = "?") -> Connection:
         """Get (or open) the cached connection to ``addr``; concurrent
-        callers share one in-flight connect (no duplicate streams)."""
+        callers share one in-flight dial (no duplicate streams).  The
+        dial is the messenger's own task, bounded by ``connect_timeout``
+        per attempt: a caller that gives up (its own deadline, or a
+        cancel) leaves it running for the others, who see its result
+        and never the first caller's cancellation."""
         if self._stopped:
             raise ConnectionResetError(f"{self.name}: messenger is shut down")
         conn = self._conns.get(addr)
         if conn is not None and not conn._closed:
             return conn
-        pending = self._pending.get(addr)
-        if pending is not None:
-            return await asyncio.shield(pending)
-        fut: asyncio.Future = asyncio.get_running_loop().create_future()
-        self._pending[addr] = fut
+        dial = self._pending.get(addr)
+        if dial is None:
+            dial = asyncio.ensure_future(self._open(addr, peer_name))
+            self._pending[addr] = dial
+            dial.add_done_callback(
+                lambda t, addr=addr: self._dial_done(addr, t))
         try:
-            conn = await self._open(addr, peer_name)
-            fut.set_result(conn)
-            return conn
-        except BaseException as e:
-            fut.set_exception(e)
-            fut.exception()  # mark retrieved for lone waiters
-            raise
-        finally:
+            return await asyncio.shield(dial)
+        except asyncio.CancelledError:
+            if asyncio.current_task().cancelling():
+                raise  # this caller was cancelled
+            # shutdown() cancelled the dial itself
+            raise ConnectionResetError(
+                f"{self.name}: messenger is shut down") from None
+
+    def _dial_done(self, addr: str, dial: asyncio.Task) -> None:
+        if self._pending.get(addr) is dial:
             del self._pending[addr]
+        if not dial.cancelled():
+            dial.exception()  # retrieved: every caller may have left
 
     async def _open(self, addr: str, peer_name: str) -> Connection:
         """Dial with retry/backoff: a single refused/reset TCP attempt is

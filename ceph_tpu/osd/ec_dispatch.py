@@ -760,6 +760,13 @@ class ECDispatcher:
             # this finally — end() is a no-op when already ended
             self.flight.end(flight, served="cancelled",
                             error="launch task cancelled")
+            # no waiter outlives its launch: whatever ended this task
+            # unanswered (a cancel at teardown, a BaseException the
+            # fault fork does not catch) cancels the ops it carried, so
+            # their op tasks end and the clients resend
+            for op in ops:
+                if not op.fut.done():
+                    op.fut.cancel()
 
     async def _run_batch_inner(self, b: _Batch, ops: list[_Op],
                                reason: str, flight: int) -> None:

@@ -22,9 +22,49 @@ os.environ.setdefault("CEPH_TPU_NO_JIT", "1")
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 
-# -- leak audit: no daemon may outlive the suite (VERDICT r3 Weak #6) ---------
+# -- one time limit per test phase ---------------------------------------------
 
 import pytest  # noqa: E402
+
+from tests._time_limit import time_limit  # noqa: E402
+
+LIMIT = 250.0
+"""Seconds each of a test's setup, call and teardown may take (see
+``tests/_time_limit.py``): about three times the longest test phase of
+a whole run with 6 pytest-xdist workers (82 s on an 8-core host)."""
+
+_stderr = None  # the real stderr, opened before output capture takes fd 2
+
+
+def pytest_configure(config):
+    global _stderr
+    config.addinivalue_line(
+        "markers", "slow: longer than the time limit run alone; "
+        "deselected with -m 'not slow'",
+    )
+    if _stderr is None:
+        _stderr = open(os.dup(2), "w", buffering=1)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_setup(item):
+    with time_limit(LIMIT, file=_stderr):
+        return (yield)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    with time_limit(LIMIT, file=_stderr):
+        return (yield)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_teardown(item, nextitem):
+    with time_limit(LIMIT, file=_stderr):
+        return (yield)
+
+
+# -- leak audit: no daemon may outlive the suite (VERDICT r3 Weak #6) ---------
 
 
 @pytest.fixture(scope="session", autouse=True)

@@ -270,6 +270,28 @@ def test_batch_failure_reaches_every_waiter(monkeypatch):
     assert all(isinstance(r, RuntimeError) for r in res)
 
 
+def test_batch_task_ended_unanswered_cancels_its_waiters(monkeypatch):
+    """A launch that ends by a BaseException the fault fork does not
+    catch (here a CancelledError leaking out of the lane) must not
+    leave its waiters pending for ever: they are cancelled, so the op
+    task ends and the client resends."""
+    monkeypatch.setattr(native, "host_engine_active", lambda: False)
+    sinfo, codec = _sinfo(2), _codec()
+    (buf,) = _bufs(sinfo, [1], seed=7)
+
+    async def leak(b, ops):
+        raise asyncio.CancelledError()
+
+    async def main():
+        disp = ECDispatcher(window=0.0, max_stripes=1 << 20)
+        monkeypatch.setattr(disp, "_launch", leak)
+        with pytest.raises(asyncio.CancelledError):
+            await asyncio.wait_for(disp.encode(sinfo, codec, buf), 5.0)
+        await disp.stop()
+
+    run(main())
+
+
 # -- event-loop liberation ---------------------------------------------------
 
 

@@ -170,6 +170,117 @@ class TestMidVectoredWriteSever:
         run(main())
 
 
+async def _silent_listener():
+    """A TCP listener that accepts and never says a word."""
+    held = []
+
+    async def hold(reader, writer):
+        held.append(writer)
+        await asyncio.Event().wait()
+
+    srv = await asyncio.start_server(hold, "127.0.0.1", 0)
+    host, port = srv.sockets[0].getsockname()[:2]
+    return srv, held, f"{host}:{port}"
+
+
+class TestHandshakeBounds:
+    """A peer that connects and then goes quiet, or a caller that gives
+    up on a dial, must never leave a waiter pending for ever."""
+
+    def test_shutdown_closes_a_silent_inbound_handshake(self):
+        """An accepted link still in its banner exchange is not yet a
+        Connection; shutdown() must close it, or the server's
+        wait_closed() waits on its transport for ever."""
+
+        async def main():
+            server = AsyncMessenger("srv", _Sink())
+            await server.bind()
+            host, port = server.addr.rsplit(":", 1)
+            reader, writer = await asyncio.open_connection(host, int(port))
+            await asyncio.sleep(0.05)  # the acceptor waits on our banner
+            await asyncio.wait_for(server.shutdown(), 2.0)
+            assert await asyncio.wait_for(reader.read(), 2.0) == b""
+            writer.close()
+
+        run(main())
+
+    def test_silent_inbound_peer_is_dropped_after_connect_timeout(self):
+        async def main():
+            server = AsyncMessenger("srv", _Sink(), connect_timeout=0.3)
+            await server.bind()
+            host, port = server.addr.rsplit(":", 1)
+            reader, writer = await asyncio.open_connection(host, int(port))
+            assert await asyncio.wait_for(reader.read(), 2.0) == b""
+            writer.close()
+            await server.shutdown()
+
+        run(main())
+
+    def test_caller_giving_up_leaves_the_shared_dial_to_the_others(self):
+        """Two callers share one dial to a peer that never answers the
+        banner.  The first gives up at its own deadline; the second
+        must see the dial's own failure, not the first caller's
+        cancellation (a CancelledError there ends an EC batch task with
+        its waiters unanswered)."""
+
+        async def main():
+            srv, held, addr = await _silent_listener()
+            client = AsyncMessenger("cli", _Sink(), reconnect_attempts=1,
+                                    connect_timeout=0.5)
+            impatient = asyncio.ensure_future(
+                asyncio.wait_for(client.connect(addr, "srv"), 0.1))
+            patient = asyncio.ensure_future(client.connect(addr, "srv"))
+            first, second = await asyncio.gather(impatient, patient,
+                                                 return_exceptions=True)
+            await client.shutdown()
+            for w in held:
+                w.close()
+            srv.close()
+            assert isinstance(first, TimeoutError)
+            assert isinstance(second, ConnectionError), repr(second)
+
+        run(main())
+
+
+class TestClosingTransport:
+    def test_writes_to_an_aborted_link_leave_its_fd_clean(self):
+        """A writer loop can still hand frames to a link whose
+        transport has aborted but whose connection_lost has not run.
+        Those bytes must be dropped: a writer registered for the dying
+        socket outlives its close in the selector, and the next socket
+        to reuse the fd number can then never register for reading."""
+        import socket
+
+        from ceph_tpu.msg.messenger import _FrameChannel
+
+        async def main():
+            loop = asyncio.get_running_loop()
+            srv = await asyncio.start_server(
+                lambda r, w: None, "127.0.0.1", 0)
+            port = srv.sockets[0].getsockname()[1]
+            tr, ch = await loop.create_connection(
+                _FrameChannel, "127.0.0.1", port)
+            fd = tr.get_extra_info("socket").fileno()
+            tr.abort()
+            ch.writelines([b"x" * 64])
+            await asyncio.sleep(0.05)  # the queued close runs
+            # a new socket takes the lowest free descriptor: open until
+            # one reuses the closed one
+            socks = []
+            try:
+                while not socks or socks[-1].fileno() != fd:
+                    assert len(socks) < 256
+                    socks.append(socket.socket())
+                loop.add_reader(fd, lambda: None)
+                loop.remove_reader(fd)
+            finally:
+                for s in socks:
+                    s.close()
+                srv.close()
+
+        run(main())
+
+
 class TestMsgrFailureThrash:
     @pytest.mark.slow
     def test_ec_cluster_consistent_under_socket_loss(self):
