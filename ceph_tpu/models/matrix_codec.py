@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import gf_pallas
 from ..ops import matrices as mx
 from ..ops.gf import gf
 from ..ops.gf_jax import (
@@ -125,6 +126,56 @@ def _mkey(matrix: np.ndarray) -> tuple:
     return tuple(tuple(int(v) for v in row) for row in np.asarray(matrix))
 
 
+# Columns per launch of a pipelined u32 GF matmul, in bytes of each
+# input row: a whole number of gf_pallas.BLOCK lanes, so every slice
+# stays on the Pallas kernel.  A sweep of 2 to 32 slices per 64 MiB
+# RS(8,3) call on a TPU v5e put 8 slices first (PERF.md, Findings).
+PIPELINE_SLICE_BYTES = 1 << 20
+
+
+def _gf_matmul_u32(engine: str, mkey: tuple, w: int, d32: np.ndarray,
+                   slice_bytes: int = PIPELINE_SLICE_BYTES) -> np.ndarray:
+    """[rows, N4] host u32 -> [len(mkey), N4] host u32 through the GF
+    kernel, recorded under ``engine`` as one profiler call.
+
+    An input that splits evenly into two or more column slices of
+    ``slice_bytes`` per row runs as one launch per slice, its copies
+    overlapped: every slice's upload is staged at once (transfers that
+    run together move more bytes per second than one alone), each
+    slice's kernel is enqueued behind its upload and its download
+    started behind the kernel, and the host result fills slice by
+    slice while later transfers are still in flight.  Any other input
+    is one launch.  The bytes are the same either way."""
+    if slice_bytes <= 0 or slice_bytes % (4 * gf_pallas.BLOCK):
+        raise ValueError(
+            f"slice of {slice_bytes} B per row is not a whole number of "
+            f"{4 * gf_pallas.BLOCK} B kernel blocks")
+    fn32 = _jit_matmul_u32(mkey, w)
+    prof = profiler()
+    rows, n4 = d32.shape
+    lanes = slice_bytes // 4
+    slices = n4 // lanes
+    if slices < 2 or n4 % lanes:
+        return prof.call_jitted(
+            engine, (mkey, d32.shape), fn32, (d32,),
+            nbytes=d32.nbytes, shape=d32.shape, wrap=np.asarray,
+        )
+    parts = [d32[:, i * lanes:(i + 1) * lanes] for i in range(slices)]
+    key = (mkey, parts[0].shape)
+    run = prof.executable(engine, key, fn32, (parts[0],))
+    with prof.timed(engine, key, nbytes=d32.nbytes, shape=d32.shape,
+                    launches=slices):
+        outs = []
+        for x in jax.device_put(parts):
+            y = run(x)  # x is donated, as on the one-launch path
+            y.copy_to_host_async()
+            outs.append(y)
+        out = np.empty((len(mkey), n4), dtype=np.uint32)
+        for i, y in enumerate(outs):
+            out[:, i * lanes:(i + 1) * lanes] = np.asarray(y)
+        return out
+
+
 # -- engine failure classification (osd/ec_failover) --------------------------
 #
 # The failover layer must split "the DEVICE is broken" (replay the batch
@@ -215,14 +266,7 @@ class MatrixErasureCode(ErasureCode):
         """u32-lane fast path ([k, N4] uint32 -> [m, N4] uint32): the
         OSD data path (ec_util) keeps the whole pipeline in u32 so the
         only byte movement is the stripe-layout transpose."""
-        fn32 = _jit_matmul_u32(self._mkey, self.w)
-        # kernel-boundary tap (ops.profiler): the (matrix, shape) key is
-        # the jit-cache signature, so compile-vs-cached splits honestly;
-        # call_jitted AOT-times the compile separately when jax allows
-        return profiler().call_jitted(
-            "gf_encode", (self._mkey, d32.shape), fn32, (d32,),
-            nbytes=d32.size * 4, shape=d32.shape, wrap=np.asarray,
-        )
+        return _gf_matmul_u32("gf_encode", self._mkey, self.w, d32)
 
     def encode_shards_u32(self, d3: np.ndarray) -> np.ndarray:
         """The OSD stack's hot entry: [S, k, C4] u32 stripe view ->
@@ -341,13 +385,8 @@ class MatrixErasureCode(ErasureCode):
         if arr.shape[-1] % 4 == 0:
             # decode stays on the u32 lanes too (free host views, no
             # device relayout) — same policy as encode_chunks
-            fn32 = _jit_matmul_u32(rm_key, self.w)
-            return profiler().call_jitted(
-                "gf_decode", (rm_key, arr.shape), fn32,
-                (bytes_to_u32(arr),),
-                nbytes=arr.size, shape=arr.shape,
-                wrap=lambda o: u32_to_bytes(np.asarray(o)),
-            )
+            return u32_to_bytes(_gf_matmul_u32(
+                "gf_decode", rm_key, self.w, bytes_to_u32(arr)))
         fn = _jit_matmul(rm_key, self.w)
         return np.asarray(fn(arr))
 

@@ -46,13 +46,16 @@ _KERNEL_AXES = dict(size_min=4096.0, lat_min=1e-6)
 
 
 class _EngineStats:
-    __slots__ = ("calls", "compile_calls", "cache_hits", "compile_time",
-                 "exec_time", "bytes", "exec_bytes", "shapes", "hist",
-                 "aot_splits", "first_exec_time", "first_execs",
-                 "device")
+    __slots__ = ("calls", "launches", "compile_calls", "cache_hits",
+                 "compile_time", "exec_time", "bytes", "exec_bytes",
+                 "shapes", "hist", "aot_splits", "first_exec_time",
+                 "first_execs", "device")
 
     def __init__(self):
         self.calls = 0
+        # device launches behind those calls: a pipelined codec call
+        # cuts its input into slices and launches once per slice
+        self.launches = 0
         self.compile_calls = 0
         self.cache_hits = 0
         self.compile_time = 0.0
@@ -110,7 +113,7 @@ class KernelProfiler:
     # -- recording -----------------------------------------------------------
     def record(self, engine: str, key: Hashable, seconds: float,
                nbytes: int = 0, shape: Any = None,
-               compiled: bool | None = None) -> None:
+               compiled: bool | None = None, launches: int = 1) -> None:
         """``compiled`` overrides the first-sighting classification for
         callers that know (bench.py records a chained-scan marginal as
         steady-state even on a shape it never timed standalone;
@@ -127,6 +130,7 @@ class KernelProfiler:
             if st is None:
                 st = self._engines[engine] = _EngineStats()
             st.calls += 1
+            st.launches += launches
             st.bytes += int(nbytes)
             first = sig not in self._seen
             self._seen.add(sig)
@@ -155,27 +159,26 @@ class KernelProfiler:
 
     @contextlib.contextmanager
     def timed(self, engine: str, key: Hashable, nbytes: int = 0,
-              shape: Any = None, compiled: bool | None = None):
+              shape: Any = None, compiled: bool | None = None,
+              launches: int = 1):
         t0 = time.perf_counter()
         try:
             yield
         finally:
             self.record(engine, key, time.perf_counter() - t0,
-                        nbytes=nbytes, shape=shape, compiled=compiled)
+                        nbytes=nbytes, shape=shape, compiled=compiled,
+                        launches=launches)
 
-    def call_jitted(self, engine: str, key: Hashable, fn, args: tuple,
-                    *, nbytes: int = 0, shape: Any = None, wrap=None):
-        """Call a (possibly jitted) kernel under the profiler, shrinking
-        the "compile includes the first execution" blind spot: on the
-        first sighting of a signature, if ``fn`` exposes jax's AOT path
+    def executable(self, engine: str, key: Hashable, fn, args: tuple):
+        """What to launch ``fn`` as for this signature, shrinking the
+        "compile includes the first execution" blind spot: on the first
+        sighting of a signature, if ``fn`` exposes jax's AOT path
         (``fn.lower(*args).compile()``), the compile is timed as its own
-        compile-call (zero bytes) and the first execution then lands in
+        compile-call (zero bytes), so the first execution then lands in
         the steady-state numbers like any cached call; the engine's
         profile entry is marked ``aot_split``.  Callables without
-        ``.lower`` (CEPH_TPU_NO_JIT eager fns, native wrappers) keep the
-        current first-call split.  ``wrap`` post-processes the result
-        INSIDE the exec timing (e.g. np.asarray, so host
-        materialization stays accounted as before)."""
+        ``.lower`` (CEPH_TPU_NO_JIT eager fns, native wrappers) are
+        returned as they are and keep the first-call split."""
         sig = (engine, key)
         with self._lock:
             exe = self._aot.get(sig)
@@ -214,7 +217,15 @@ class KernelProfiler:
                             self._aot[sig] = exe
                             while len(self._aot) > self._aot_cap:
                                 self._aot.pop(next(iter(self._aot)))
-        f = fn if exe is None else exe
+        return fn if exe is None else exe
+
+    def call_jitted(self, engine: str, key: Hashable, fn, args: tuple,
+                    *, nbytes: int = 0, shape: Any = None, wrap=None):
+        """Call a (possibly jitted) kernel under the profiler as one
+        launch, compiled apart through :meth:`executable`.  ``wrap``
+        post-processes the result INSIDE the exec timing (e.g.
+        np.asarray, so host materialization stays accounted)."""
+        f = self.executable(engine, key, fn, args)
         with self.timed(engine, key, nbytes=nbytes, shape=shape):
             out = f(*args)
             return out if wrap is None else wrap(out)
@@ -270,6 +281,7 @@ class KernelProfiler:
             for name, st in picked:
                 engines[name] = {
                     "calls": st.calls,
+                    "launches": st.launches,
                     "jit_cache": {
                         "misses": st.compile_calls,
                         "hits": st.cache_hits,

@@ -141,6 +141,28 @@ class TestInstrumentationTaps:
         engines = p.dump()["engines"]
         assert any(e.startswith("gf_decode") for e in engines), engines
 
+    def test_launches_beside_calls(self):
+        """``dump_kernel_profile`` reports ``launches`` beside
+        ``calls``: a single-launch engine (the fused stripe encode)
+        reads one per call; a recorded multi-launch call adds its
+        count."""
+        from ceph_tpu.models import registry
+
+        p = profiler()
+        p.reset()
+        codec = registry.instance().factory(
+            "isa", {"k": "2", "m": "1", "technique": "reed_sol_van"}
+        )
+        d3 = np.zeros((3, 2, 256), dtype=np.uint32)
+        codec.encode_shards_u32(d3)
+        codec.encode_shards_u32(d3)
+        e = p.dump()["engines"]["ec_shards"]
+        assert (e["calls"], e["launches"]) == (2, 2)
+        p.record("e", "k", 0.1, launches=8)
+        p.record("e", "k", 0.1)
+        e = p.dump()["engines"]["e"]
+        assert (e["calls"], e["launches"]) == (2, 9)
+
     def test_crush_mapper_reports(self):
         from ceph_tpu.crush import mapper, mapper_jax
         from ceph_tpu.crush.map import CrushMap
