@@ -2584,7 +2584,14 @@ class OSD(Dispatcher):
         else:
             hashes = StripeHashes.from_dict(hashes.to_dict())
         if shard_bufs is not None:
-            hashes.set_range(plan.will_write[0] // sinfo.stripe_width, shard_bufs)
+            pec = self.perf.get("ec")
+            t0 = time.perf_counter()
+            chunks, calls = hashes.set_range(
+                plan.will_write[0] // sinfo.stripe_width, shard_bufs
+            )
+            pec.observe("hinfo_time", time.perf_counter() - t0)
+            pec.inc("hinfo_chunks", chunks)
+            pec.inc("hinfo_calls", calls)
         hashes.truncate_stripes(
             sinfo.logical_to_next_stripe_offset(plan.new_size) // sinfo.stripe_width
         )

@@ -59,6 +59,15 @@ def create_ec_perf(perf):
                       "EC encode buffer size x device wall time")
     pec.add_histogram("decode_time_histogram",
                       "EC decode shard bytes x device wall time")
+    # the stripe-hash table each EC write commit installs
+    # (StripeHashes.set_range): chunks / calls reads how many chunk
+    # crcs one native call covers
+    pec.add_time_avg("hinfo_time",
+                     "stripe-hash table update wall time per commit")
+    pec.add_counter("hinfo_chunks",
+                    "chunk crcs computed for commits' stripe-hash tables")
+    pec.add_counter("hinfo_calls",
+                    "native crc calls those chunk crcs took")
     # cross-op microbatch dispatcher (osd_ec_dispatch; see
     # osd/ec_dispatch.py): coalesced-launch + bucketing evidence
     pec.add_counter("dispatch_batches", "coalesced device launches")

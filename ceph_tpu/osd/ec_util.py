@@ -18,6 +18,7 @@ TPU re-expression of ``ECUtil`` (reference:src/osd/ECUtil.{h,cc}):
 
 from __future__ import annotations
 
+import functools
 import logging
 from typing import Mapping, Sequence
 
@@ -428,6 +429,11 @@ def decode_concat_fallback(
 # -- StripeHashes ------------------------------------------------------------
 
 
+@functools.lru_cache(maxsize=None)
+def _zero_chunk_crc(chunk_size: int) -> int:
+    return native.crc32c(CRC_SEED, np.zeros(chunk_size, dtype=np.uint8))
+
+
 class StripeHashes:
     """Per-(shard, stripe) crc32c table — the overwrite-safe HashInfo.
 
@@ -458,40 +464,37 @@ class StripeHashes:
 
     @staticmethod
     def _chunk_crcs(buf: np.ndarray, chunk_size: int) -> list[int]:
-        buf = np.asarray(buf, dtype=np.uint8)
-        if buf.size % chunk_size != 0:
-            raise ValueError(
-                f"shard buffer {buf.size} not a multiple of chunk {chunk_size}"
-            )
-        return [
-            int(native.crc32c(CRC_SEED, buf[o : o + chunk_size]))
-            for o in range(0, buf.size, chunk_size)
-        ]
+        """Every chunk's crc of a shard extent, in one native call;
+        ValueError unless the extent is whole chunks."""
+        return native.crc32c_chunks(CRC_SEED, buf, chunk_size).tolist()
 
     def zero_crc(self) -> int:
-        return int(
-            native.crc32c(CRC_SEED, np.zeros(self.chunk_size, dtype=np.uint8))
-        )
+        return _zero_chunk_crc(self.chunk_size)
 
     def set_range(
         self, first_stripe: int, shard_bufs: Mapping[int, np.ndarray]
-    ) -> None:
+    ) -> tuple[int, int]:
         """Install crcs for the stripes covered by ``shard_bufs`` (each a
         whole number of chunks starting at stripe ``first_stripe``).
         Holes below ``first_stripe`` (write past the old end) are chunks
-        the store zero-fills, so they get the zero-chunk crc."""
+        the store zero-fills, so they get the zero-chunk crc.  Returns
+        (chunks hashed, native crc calls made)."""
         if sorted(shard_bufs) != list(range(self.num_shards)):
             raise ValueError(
                 f"set_range covers shards {sorted(shard_bufs)}, "
                 f"table tracks 0..{self.num_shards - 1}"
             )
         zc = self.zero_crc()
+        chunks = calls = 0
         for shard, buf in shard_bufs.items():
             row = self.crcs[shard]
             new = self._chunk_crcs(np.asarray(buf), self.chunk_size)
+            chunks += len(new)
+            calls += bool(new)
             if len(row) < first_stripe:
                 row.extend([zc] * (first_stripe - len(row)))
             row[first_stripe : first_stripe + len(new)] = new
+        return chunks, calls
 
     def truncate_stripes(self, count: int) -> None:
         """Drop entries past ``count`` stripes; zero-extend up to it."""

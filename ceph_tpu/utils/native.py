@@ -362,6 +362,31 @@ def crc32c_view(crc: int, buf, n: int | None = None) -> int:
         return fn(crc, a.__array_interface__["data"][0], ln)
 
 
+_crc_chunks_raw = None
+
+
+def crc32c_chunks(crc: int, data: np.ndarray, chunk: int) -> np.ndarray:
+    """crc32c of each ``chunk``-byte slice of ``data``, every one seeded
+    with ``crc``, in ONE native call: ``out[i] == crc32c(crc,
+    data[i * chunk:(i + 1) * chunk])``, as uint32.  A stripe-hash table
+    row costs one call per shard rather than one per chunk, each of
+    which would pay :func:`crc32c`'s conversion scaffolding."""
+    global _crc_chunks_raw
+    if _crc_chunks_raw is None:  # benign race, as in _crc_fn
+        _crc_chunks_raw = ctypes.CFUNCTYPE(
+            None, ctypes.c_uint32, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_void_p,
+        )(("crc32c_chunks", lib()))
+    buf = np.ascontiguousarray(data, dtype=np.uint8).reshape(-1)
+    if chunk <= 0 or buf.size % chunk:
+        raise ValueError(f"buffer {buf.size} not a multiple of chunk {chunk}")
+    out = np.empty(buf.size // chunk, dtype=np.uint32)
+    if out.size:
+        _crc_chunks_raw(crc & 0xFFFFFFFF, buf.ctypes.data, buf.size, chunk,
+                        out.ctypes.data)
+    return out
+
+
 def rs_vandermonde_matrix(k: int, m: int, w: int) -> np.ndarray:
     """Independently-coded systematic RS-Vandermonde oracle (see
     native/ec_cpu.cc): cross-checks the python construction."""

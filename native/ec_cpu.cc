@@ -457,6 +457,15 @@ uint32_t crc32c_sw(uint32_t crc, const uint8_t* data, int64_t n) {
 #endif
 }
 
+// crc32c of each chunk-byte slice of data[0, n), every one seeded with
+// seed, into out[n / chunk]: a shard's whole per-stripe table in one
+// call instead of one call per chunk (osd/ec_util.py StripeHashes)
+void crc32c_chunks(uint32_t seed, const uint8_t* data, int64_t n,
+                   int64_t chunk, uint32_t* out) {
+  for (int64_t i = 0, count = n / chunk; i < count; i++)
+    out[i] = crc32c_sw(seed, data + i * chunk, chunk);
+}
+
 // table-path reference, exported so the test suite can cross-check the
 // hardware instruction against the software tables on any input
 uint32_t crc32c_table(uint32_t crc, const uint8_t* data, int64_t n) {

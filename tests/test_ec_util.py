@@ -124,6 +124,65 @@ class TestCrc32c:
             assert native.crc32c(0xFFFFFFFF, buf) == crc_ref(0xFFFFFFFF, buf)
 
 
+def _per_chunk_table(shard_bufs, chunk):
+    """A stripe-hash table row built one native crc32c call per chunk."""
+    return [
+        [native.crc32c(0xFFFFFFFF, buf[o:o + chunk])
+         for o in range(0, buf.size, chunk)]
+        for _, buf in sorted(shard_bufs.items())
+    ]
+
+
+class TestStripeHashesBatched:
+    """StripeHashes hashes a shard's chunks in one native call; its
+    tables are those of one call per chunk, value for value."""
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_full_table_matches_per_chunk_calls(self, seed):
+        rng = np.random.default_rng(seed)
+        bufs = {s: rng.integers(0, 256, 128 * 4096, dtype=np.uint8)
+                for s in range(11)}
+        sh = ec_util.StripeHashes(11, 4096)
+        assert sh.set_range(0, bufs) == (11 * 128, 11)
+        assert sh.to_dict() == {"chunk_size": 4096,
+                                "crcs": _per_chunk_table(bufs, 4096)}
+
+    def test_partial_range_past_end_zero_fills(self):
+        rng = np.random.default_rng(4)
+        cs = 512
+        sh = ec_util.StripeHashes(3, cs)
+        first = {s: rng.integers(0, 256, 2 * cs, dtype=np.uint8)
+                 for s in range(3)}
+        sh.set_range(0, first)
+        later = {s: rng.integers(0, 256, 3 * cs, dtype=np.uint8)
+                 for s in range(3)}
+        assert sh.set_range(5, later) == (9, 3)
+        zero = native.crc32c(0xFFFFFFFF, np.zeros(cs, np.uint8))
+        want_first = _per_chunk_table(first, cs)
+        want_later = _per_chunk_table(later, cs)
+        for s in range(3):
+            assert sh.crcs[s] == want_first[s] + [zero] * 3 + want_later[s]
+
+    def test_verify_accepts_true_and_rejects_flipped_byte(self):
+        rng = np.random.default_rng(5)
+        cs = 4096
+        bufs = {s: rng.integers(0, 256, 8 * cs, dtype=np.uint8)
+                for s in range(4)}
+        sh = ec_util.StripeHashes(4, cs)
+        sh.set_range(0, bufs)
+        for s, buf in bufs.items():
+            assert sh.verify(s, 0, buf)
+            assert sh.verify(s, 3, buf[3 * cs:5 * cs])
+            bad = buf.copy()
+            bad[5 * cs + 17] ^= 0x01
+            assert not sh.verify(s, 0, bad)
+
+    def test_partial_chunk_rejected(self):
+        sh = ec_util.StripeHashes(1, 512)
+        with pytest.raises(ValueError, match="not a multiple"):
+            sh.set_range(0, {0: np.zeros(700, np.uint8)})
+
+
 class TestHashInfo:
     def test_append_and_verify(self):
         hi = HashInfo(3)

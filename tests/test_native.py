@@ -62,3 +62,29 @@ def test_encode_w16_matches_oracle():
     want = G.matmul_region(M, data16)
     got = native.encode(M, data16.view(np.uint8), w=16)
     assert np.array_equal(got.view("<u2"), want)
+
+
+@pytest.mark.parametrize("seed", [0xFFFFFFFF, 0, 0x1234ABCD])
+@pytest.mark.parametrize("count", [1, 3, 128])
+@pytest.mark.parametrize("chunk", [512, 4096, 65536])
+def test_crc32c_chunks_matches_per_chunk_calls(chunk, count, seed):
+    buf = np.random.default_rng([chunk, count]).integers(
+        0, 256, size=chunk * count, dtype=np.uint8)
+    got = native.crc32c_chunks(seed, buf, chunk)
+    assert got.dtype == np.uint32
+    assert got.tolist() == [
+        native.crc32c(seed, buf[i * chunk:(i + 1) * chunk])
+        for i in range(count)
+    ]
+
+
+@pytest.mark.parametrize("chunk", [512, 4096, 65536])
+def test_crc32c_chunks_of_nothing_is_empty(chunk):
+    got = native.crc32c_chunks(0xFFFFFFFF, np.empty(0, np.uint8), chunk)
+    assert got.dtype == np.uint32 and got.size == 0
+
+
+@pytest.mark.parametrize("size,chunk", [(4097, 4096), (100, 0)])
+def test_crc32c_chunks_refuses_partial_chunks(size, chunk):
+    with pytest.raises(ValueError):
+        native.crc32c_chunks(0, np.zeros(size, np.uint8), chunk)

@@ -15,6 +15,7 @@ import numpy as np
 from ceph_tpu.osd.ec_util import StripeHashes
 from ceph_tpu.rados import MiniCluster, RadosError
 from ceph_tpu.store import CollectionId, ObjectId
+from ceph_tpu.utils import native
 
 
 def run(coro):
@@ -190,6 +191,48 @@ def test_ec_k4m2_two_failures():
                 await cluster.kill_osd(v)
                 await cluster.wait_for_osd_down(v)
             assert await io.read("big") == big  # 2-erasure reconstruct
+
+    run(main())
+
+
+def test_ec_write_full_hashes_a_shard_per_native_call():
+    """A 4 MiB write_full on k=8 m=3 with 4 KiB chunks spans 128 stripes:
+    the primary's commit hashes each shard's 128 chunks in one native
+    crc call, and the stored table matches a per-chunk crc32c."""
+
+    async def main():
+        async with MiniCluster(n_osds=16) as cluster:
+            cl = await cluster.client()
+            code, status, _ = await cl.command({
+                "prefix": "osd erasure-code-profile set", "name": "rs83",
+                "profile": {"plugin": "isa", "technique": "reed_sol_van",
+                            "k": "8", "m": "3",
+                            "crush-failure-domain": "osd"},
+            })
+            assert code == 0, status
+            await cl.create_pool("ec83", "erasure",
+                                 erasure_code_profile="rs83", pg_num=4)
+            io = cl.io_ctx("ec83")
+            blob = np.random.default_rng(11).integers(
+                0, 256, 4 << 20, dtype=np.uint8).tobytes()
+            pool = cl.osdmap.lookup_pool("ec83")
+            pg, acting, primary = cl.osdmap.object_to_acting("big", pool.id)
+            pec = cluster.osds[primary].perf.get("ec")
+            before = pec.get("hinfo_chunks"), pec.get("hinfo_calls")
+            await io.write_full("big", blob)
+            chunks = pec.get("hinfo_chunks") - before[0]
+            calls = pec.get("hinfo_calls") - before[1]
+            assert (chunks, calls) == (11 * 128, 11)
+            assert pec.get("hinfo_time")[1] >= 1
+            assert await io.read("big") == blob
+            store = cluster.stores[acting[0]]
+            cid, soid = CollectionId(f"{pg}s0"), ObjectId("big", 0)
+            shard = store.read(cid, soid)
+            table = json.loads(store.getattr(cid, soid, StripeHashes.XATTR_KEY))
+            assert table["crcs"][0] == [
+                native.crc32c(0xFFFFFFFF, shard[o:o + 4096])
+                for o in range(0, len(shard), 4096)
+            ]
 
     run(main())
 
