@@ -928,12 +928,7 @@ def _bench_codec_stack(deadline: float | None) -> float:
     the registry-built RS(8,3) codec, whole-buffer in, shards out."""
     from ceph_tpu.models import registry
     from ceph_tpu.osd import ec_util
-    from ceph_tpu.utils import native as _native
 
-    # pick serial-vs-all-cores for the native stripe engine by
-    # measurement (memory-bound containers LOSE to parallel; real
-    # multi-core hosts multiply) — the verdict is logged by the caller
-    _native.calibrate_stripe_workers()
     codec = registry.instance().factory(
         "isa", {"plugin": "isa", "technique": "reed_sol_van",
                 "k": str(K), "m": str(M)},

@@ -205,7 +205,7 @@ class AccelClient:
         local ``_run_sync`` so the dispatcher's completion path is
         lane-agnostic, plus an ``info`` dict with the reply's
         accelerator-side evidence: ``served`` (the engine that
-        produced the bytes — device/mesh/native_direct/fallback; rides
+        produced the bytes — device/mesh/fallback/fallback_direct; rides
         the flight record as ``remote_served``) and ``queue_wait_s``
         (the accel-side coalesce wait — the op waterfall's
         accel_queue_wait hop).  ``seconds`` is the accelerator's

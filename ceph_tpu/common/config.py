@@ -248,8 +248,8 @@ OPTIONS: dict[str, Option] = _opts(
            "route EC encode/reconstruct through the device-mesh engine "
            "(k+m shard rows on mesh rows, ICI all-gather reconstruct; "
            "the messenger keeps carrying control traffic) — "
-           "ceph_tpu.parallel.engine.  With osd_ec_dispatch on the "
-           "mesh is a dispatcher LANE: coalescing, QoS pacing, the "
+           "ceph_tpu.parallel.engine.  The mesh is a dispatcher "
+           "LANE (ceph_tpu.osd.ec_dispatch): coalescing, QoS pacing, the "
            "launch deadline, and engine failover all govern mesh "
            "traffic; batch keys carry the mesh slice and stripe "
            "bucketing aligns to mesh_size x bucket"),
@@ -258,11 +258,6 @@ OPTIONS: dict[str, Option] = _opts(
            "exposes); a nonzero value pins the mesh to the first N "
            "devices — bench.py's mesh phase sweeps this dimension for "
            "per-chip scaling efficiency"),
-    Option("osd_ec_dispatch", bool, True,
-           "coalesce concurrent EC encode/decode requests into one "
-           "padded device launch off the event loop "
-           "(ceph_tpu.osd.ec_dispatch); with osd_ec_mesh on, mesh "
-           "launches ride the same dispatcher as a first-class lane"),
     Option("osd_ec_dispatch_window", float, 0.0005,
            "EC dispatcher coalescing window (s): a batch flushes this "
            "long after its first request unless the stripe threshold "

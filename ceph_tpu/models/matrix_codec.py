@@ -282,17 +282,16 @@ class MatrixErasureCode(ErasureCode):
 
     def _host_matmul(self, matrix: np.ndarray, arr: np.ndarray) -> np.ndarray:
         """Pure-host GF matmul — the failover replay engine.  Never
-        enters jax: native C when loadable and aligned (bit-identical
-        to the tables, pinned by tests), else the numpy oracle every
-        device engine is pinned against, so a replayed batch is byte
-        identical to what the device would have produced."""
+        enters jax: native C when the library loads and the rows are
+        aligned (bit-identical to the tables, pinned by tests), else the
+        numpy oracle every device engine is pinned against, so a
+        replayed batch is byte identical to what the device would have
+        produced."""
         from ..utils import native as _native
 
-        if self.w == 8 and arr.shape[-1] % 8 == 0:
-            try:
-                return _native.encode(matrix, arr)
-            except Exception:  # library unbuildable: numpy oracle below
-                pass
+        if (self.w == 8 and arr.shape[-1] % 8 == 0
+                and _native.host_engine_active()):
+            return _native.encode(matrix, arr)
         G = gf(self.w)
         if self.w == 16:
             # bytes are pairs of native-endian GF(2^16) elements on the
@@ -367,21 +366,6 @@ class MatrixErasureCode(ErasureCode):
             )
         RM, rm_key = self._recovery_matrix(present, missing)
         arr = np.asarray(chunks, dtype=np.uint8)
-        from ..utils import native as _native
-
-        if (
-            self.w == 8 and arr.shape[-1] % 8 == 0
-            and type(self) is MatrixErasureCode
-            and _native.host_engine_active()
-        ):
-            # CPU host: the native GFNI/u64 engine reconstructs with no
-            # host<->device copies (same routing policy as the encode
-            # stack; bytes identical — the GF algebra is exact)
-            with profiler().timed("gf_decode_native",
-                                  (rm_key, arr.shape),
-                                  nbytes=arr.size, shape=arr.shape,
-                                  compiled=False):
-                return _native.encode(RM, arr)
         if arr.shape[-1] % 4 == 0:
             # decode stays on the u32 lanes too (free host views, no
             # device relayout) — same policy as encode_chunks

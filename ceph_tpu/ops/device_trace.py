@@ -655,7 +655,7 @@ class FlightRecorder:
         reading ``dump_launch_history`` cannot tell which fault domain
         the replay answered for (ISSUE 10 satellite).
         ``remote_served`` names the engine the ACCELERATOR served a
-        remote-lane batch from (device/mesh/native_direct/fallback —
+        remote-lane batch from (device/mesh/fallback/fallback_direct —
         the reply piggybacks it), so the client-side record shows
         whether the shared device, or its host fallback, actually
         produced the bytes."""

@@ -33,7 +33,6 @@ Positional shard roles come from the acting set: acting[i] serves shard i
 from __future__ import annotations
 
 import asyncio
-import contextlib
 import json
 import logging
 import os
@@ -48,7 +47,7 @@ from ..msg import AsyncMessenger, Connection, Dispatcher, messages
 from ..msg.message import Message
 from ..store import CollectionId, MemStore, ObjectId, ObjectStore, Transaction
 from ..store.objectstore import NeedsMkfs
-from . import ec_transaction, ec_util
+from . import ec_transaction
 from . import snaps as snaps_mod
 from .ec_util import StripeHashes, StripeInfo
 from .osdmap import CRUSH_ITEM_NONE, OSDMap, PGid, Pool, POOL_TYPE_ERASURE
@@ -360,11 +359,9 @@ class OSD(Dispatcher):
             perf=pqos,
         )
         # the mesh EC data path (osd_ec_mesh): shard rows on mesh rows,
-        # ICI all-gather reconstruct; None = host/TCP-only path.  With
-        # the dispatcher on (default) the mesh is a DISPATCHER LANE —
-        # coalescing, QoS pacing, launch deadlines, and failover all
-        # apply to mesh traffic (ISSUE 8); only the dispatcher-off
-        # config keeps the old direct per-op route
+        # ICI all-gather reconstruct; None = single-device only.  The
+        # mesh is a DISPATCHER LANE — coalescing, QoS pacing, launch
+        # deadlines, and failover all apply to mesh traffic (ISSUE 8)
         self.ec_mesh = None
         if getattr(cfg, "osd_ec_mesh", False):
             from ..parallel.engine import get_mesh_engine
@@ -372,67 +369,63 @@ class OSD(Dispatcher):
             self.ec_mesh = get_mesh_engine(
                 getattr(cfg, "osd_ec_mesh_devices", 0)
             )
-        # cross-op EC microbatch dispatcher (default on), plus the
-        # engine health supervisor (osd/ec_failover): fatal launch
+        # cross-op EC microbatch dispatcher (every EC op's route), plus
+        # the engine health supervisor (osd/ec_failover): fatal launch
         # failures — on the single-device AND mesh lanes — replay on
         # the host fallback and trip the breaker; while tripped, the
         # QoS scheduler treats capacity as degraded and ec_background
         # pacing squeezes to reservation
-        self.ec_dispatch = None
-        self.ec_supervisor = None
-        self.accel_client = None
-        if getattr(cfg, "osd_ec_dispatch", True):
-            from ..accel.router import AccelRouter
-            from .ec_dispatch import ECDispatcher
-            from .ec_failover import EngineSupervisor
+        from ..accel.router import AccelRouter
+        from .ec_dispatch import ECDispatcher
+        from .ec_failover import EngineSupervisor
 
-            # constructed even with failover configured OFF (enabled
-            # gates the state machine, not the object): `config set
-            # osd_ec_engine_failover true` on a RUNNING osd must arm
-            # the breaker, not silently no-op while config show says on
-            self.ec_supervisor = EngineSupervisor(
-                enabled=cfg.osd_ec_engine_failover,
-                perf=pec,
-                probe_interval=cfg.osd_ec_probe_interval,
-                on_degraded=lambda d: setattr(
-                    self.scheduler, "capacity_degraded", d
-                ),
-            )
-            # the remote dispatcher lane (ISSUE 10 -> 11): coalesced
-            # batches ship to the accelerator FLEET over the
-            # messenger — the AccelRouter holds one client per
-            # mon-published AccelMap entry (fed from every map push in
-            # _handle_map) and keeps osd_ec_accel_addr as the
-            # single-entry static-fleet compat shim.  Constructed even
-            # with osd_ec_accel_mode=off (the default) — `config set
-            # osd_ec_accel_addr/mode` on a RUNNING osd must arm the
-            # lane live, exactly like the breaker above
-            self.accel_client = AccelRouter(
-                self.messenger,
-                addr=cfg.osd_ec_accel_addr,
-                mode=cfg.osd_ec_accel_mode,
-                deadline=cfg.osd_ec_accel_deadline,
-                retry_interval=cfg.osd_ec_accel_retry_interval,
-                stale_interval=cfg.osd_ec_accel_stale_interval,
-                perf=pacc,
-                perf_collection=self.perf,
-            )
-            self.ec_dispatch = ECDispatcher(
-                perf=pec,
-                window=cfg.osd_ec_dispatch_window,
-                max_stripes=cfg.osd_ec_dispatch_max_stripes,
-                bucket=cfg.osd_ec_dispatch_bucket,
-                scheduler=self.scheduler,
-                supervisor=self.ec_supervisor,
-                launch_deadline=cfg.osd_ec_launch_deadline,
-                mesh_engine=self.ec_mesh,
-                launch_history=cfg.osd_ec_launch_history,
-                remote=self.accel_client,
-            )
-            self.ec_dispatch.inject_engine_failure = \
-                cfg.ec_inject_engine_failure
-            self.ec_dispatch.inject_launch_hang = \
-                cfg.ec_inject_launch_hang
+        # constructed even with failover configured OFF (enabled
+        # gates the state machine, not the object): `config set
+        # osd_ec_engine_failover true` on a RUNNING osd must arm
+        # the breaker, not silently no-op while config show says on
+        self.ec_supervisor = EngineSupervisor(
+            enabled=cfg.osd_ec_engine_failover,
+            perf=pec,
+            probe_interval=cfg.osd_ec_probe_interval,
+            on_degraded=lambda d: setattr(
+                self.scheduler, "capacity_degraded", d
+            ),
+        )
+        # the remote dispatcher lane (ISSUE 10 -> 11): coalesced
+        # batches ship to the accelerator FLEET over the
+        # messenger — the AccelRouter holds one client per
+        # mon-published AccelMap entry (fed from every map push in
+        # _handle_map) and keeps osd_ec_accel_addr as the
+        # single-entry static-fleet compat shim.  Constructed even
+        # with osd_ec_accel_mode=off (the default) — `config set
+        # osd_ec_accel_addr/mode` on a RUNNING osd must arm the
+        # lane live, exactly like the breaker above
+        self.accel_client = AccelRouter(
+            self.messenger,
+            addr=cfg.osd_ec_accel_addr,
+            mode=cfg.osd_ec_accel_mode,
+            deadline=cfg.osd_ec_accel_deadline,
+            retry_interval=cfg.osd_ec_accel_retry_interval,
+            stale_interval=cfg.osd_ec_accel_stale_interval,
+            perf=pacc,
+            perf_collection=self.perf,
+        )
+        self.ec_dispatch = ECDispatcher(
+            perf=pec,
+            window=cfg.osd_ec_dispatch_window,
+            max_stripes=cfg.osd_ec_dispatch_max_stripes,
+            bucket=cfg.osd_ec_dispatch_bucket,
+            scheduler=self.scheduler,
+            supervisor=self.ec_supervisor,
+            launch_deadline=cfg.osd_ec_launch_deadline,
+            mesh_engine=self.ec_mesh,
+            launch_history=cfg.osd_ec_launch_history,
+            remote=self.accel_client,
+        )
+        self.ec_dispatch.inject_engine_failure = \
+            cfg.ec_inject_engine_failure
+        self.ec_dispatch.inject_launch_hang = \
+            cfg.ec_inject_launch_hang
         prec = self.perf.create("recovery")
         prec.add_counter("pushes", "objects/shards pushed")
         prec.add_counter("reservation_waits",
@@ -549,11 +542,10 @@ class OSD(Dispatcher):
         self.op_tracker = OpTracker(
             history_size=cfg.osd_op_history_size
         )
-        if self.ec_dispatch is not None:
-            # SLOW_OPS -> launch correlation (ROADMAP 5a): an op dump
-            # names the device launch that carried it, straight from
-            # the dispatcher's flight recorder
-            self.op_tracker.launch_lookup = self.ec_dispatch.flight.lookup
+        # SLOW_OPS -> launch correlation (ROADMAP 5a): an op dump names
+        # the device launch that carried it, straight from the
+        # dispatcher's flight recorder
+        self.op_tracker.launch_lookup = self.ec_dispatch.flight.lookup
         self._slow_reported = 0  # slow ops already clog'd (edge trigger)
         # op waterfall sampling (ISSUE 12): 1-in-N client ops get full
         # hop spans (recorded + reply-piggybacked + stack.lat_* fed)
@@ -582,66 +574,37 @@ class OSD(Dispatcher):
                 self.remote_reserver.set_max(v),
             )),
             # dispatcher knobs stay live for `config set` tuning
-            ("osd_ec_dispatch_window", lambda _n, v: (
-                self.ec_dispatch is not None
-                and setattr(self.ec_dispatch, "window", float(v))
-            )),
-            ("osd_ec_dispatch_max_stripes", lambda _n, v: (
-                self.ec_dispatch is not None
-                and setattr(self.ec_dispatch, "max_stripes", int(v))
-            )),
-            ("osd_ec_dispatch_bucket", lambda _n, v: (
-                self.ec_dispatch is not None
-                and setattr(self.ec_dispatch, "bucket", bool(v))
-            )),
+            ("osd_ec_dispatch_window", lambda _n, v: setattr(
+                self.ec_dispatch, "window", float(v))),
+            ("osd_ec_dispatch_max_stripes", lambda _n, v: setattr(
+                self.ec_dispatch, "max_stripes", int(v))),
+            ("osd_ec_dispatch_bucket", lambda _n, v: setattr(
+                self.ec_dispatch, "bucket", bool(v))),
             # fault-domain knobs: deadline/backoff tuning and the
             # injection hooks must flip on a RUNNING osd (the fault
             # matrix arms and lifts them live)
             ("osd_ec_launch_deadline", self._on_ec_launch_deadline),
-            ("osd_ec_probe_interval", lambda _n, v: (
-                self.ec_supervisor is not None
-                and setattr(self.ec_supervisor, "probe_interval",
-                            float(v))
-            )),
-            ("osd_ec_engine_failover", lambda _n, v: (
-                self.ec_supervisor is not None
-                and self.ec_supervisor.set_enabled(bool(v))
-            )),
-            ("ec_inject_engine_failure", lambda _n, v: (
-                self.ec_dispatch is not None
-                and setattr(self.ec_dispatch, "inject_engine_failure",
-                            int(v))
-            )),
-            ("ec_inject_launch_hang", lambda _n, v: (
-                self.ec_dispatch is not None
-                and setattr(self.ec_dispatch, "inject_launch_hang",
-                            float(v))
-            )),
+            ("osd_ec_probe_interval", lambda _n, v: setattr(
+                self.ec_supervisor, "probe_interval", float(v))),
+            ("osd_ec_engine_failover",
+             lambda _n, v: self.ec_supervisor.set_enabled(bool(v))),
+            ("ec_inject_engine_failure", lambda _n, v: setattr(
+                self.ec_dispatch, "inject_engine_failure", int(v))),
+            ("ec_inject_launch_hang", lambda _n, v: setattr(
+                self.ec_dispatch, "inject_launch_hang", float(v))),
             # remote accelerator lane knobs (ISSUE 10): routing must
             # re-target / re-mode on a RUNNING osd — the fault matrix
             # and MiniCluster wiring both flip them live
-            ("osd_ec_accel_addr", lambda _n, v: (
-                self.accel_client is not None
-                and self.accel_client.set_addr(str(v))
-            )),
-            ("osd_ec_accel_mode", lambda _n, v: (
-                self.accel_client is not None
-                and self.accel_client.set_mode(str(v))
-            )),
-            ("osd_ec_accel_deadline", lambda _n, v: (
-                self.accel_client is not None
-                and setattr(self.accel_client, "deadline", float(v))
-            )),
-            ("osd_ec_accel_retry_interval", lambda _n, v: (
-                self.accel_client is not None
-                and setattr(self.accel_client, "retry_interval",
-                            float(v))
-            )),
-            ("osd_ec_accel_stale_interval", lambda _n, v: (
-                self.accel_client is not None
-                and setattr(self.accel_client, "stale_interval",
-                            float(v))
-            )),
+            ("osd_ec_accel_addr",
+             lambda _n, v: self.accel_client.set_addr(str(v))),
+            ("osd_ec_accel_mode",
+             lambda _n, v: self.accel_client.set_mode(str(v))),
+            ("osd_ec_accel_deadline", lambda _n, v: setattr(
+                self.accel_client, "deadline", float(v))),
+            ("osd_ec_accel_retry_interval", lambda _n, v: setattr(
+                self.accel_client, "retry_interval", float(v))),
+            ("osd_ec_accel_stale_interval", lambda _n, v: setattr(
+                self.accel_client, "stale_interval", float(v))),
             # QoS scheduler knobs stay live: `config set osd_op_queue
             # fifo` must switch a RUNNING osd's policy (queued waiters
             # re-order, nothing is dropped)
@@ -756,14 +719,12 @@ class OSD(Dispatcher):
         # the failover deadline, NOT the watchdog: the handle falls
         # back to the generic op-worker grace so a wedged launch still
         # marks the daemon unhealthy and still hits suicide policy.
-        self._ec_launch_handle = None
-        if self.ec_dispatch is not None:
-            self._ec_launch_handle = self.hb_map.add_worker(
-                "ec_device_launch",
-                self._ec_watchdog_grace(cfg.osd_ec_launch_deadline),
-                cfg.osd_op_thread_suicide_timeout,
-            )
-            self.ec_dispatch.set_watchdog_handle(self._ec_launch_handle)
+        self._ec_launch_handle = self.hb_map.add_worker(
+            "ec_device_launch",
+            self._ec_watchdog_grace(cfg.osd_ec_launch_deadline),
+            cfg.osd_op_thread_suicide_timeout,
+        )
+        self.ec_dispatch.set_watchdog_handle(self._ec_launch_handle)
         if cfg.lockdep:
             lockdep_enable(True)
         self._tasks: set[asyncio.Task] = set()
@@ -816,10 +777,8 @@ class OSD(Dispatcher):
     def _on_ec_launch_deadline(self, _name: str, value: float) -> None:
         """osd_ec_launch_deadline is live: it bounds future launches
         (dispatcher) and re-graces the watchdog handle."""
-        if self.ec_dispatch is not None:
-            self.ec_dispatch.launch_deadline = float(value)
-        if self._ec_launch_handle is not None:
-            self._ec_launch_handle.grace = self._ec_watchdog_grace(value)
+        self.ec_dispatch.launch_deadline = float(value)
+        self._ec_launch_handle.grace = self._ec_watchdog_grace(value)
 
     def _hb_suicide(self, worker: str) -> None:
         """A worker blew its suicide timeout: take the daemon down hard
@@ -980,27 +939,25 @@ class OSD(Dispatcher):
             )
 
         a.register("arch", _arch, "accelerator/host capability probe")
-        if self.ec_dispatch is not None:
-            a.register(
-                "dump_ec_dispatch",
-                lambda req: self.ec_dispatch.dump(),
-                "EC microbatch dispatcher: open batches, flush reasons, "
-                "pad waste, observed bucket table",
-            )
-            a.register(
-                "dump_launch_history",
-                lambda req: self.ec_dispatch.flight.dump(),
-                "device-launch flight recorder: the last N launches "
-                "(lane, batch key, QoS class, queue-wait vs device "
-                "wall, slowest member op's trace id)",
-            )
-        if self.ec_supervisor is not None:
-            a.register(
-                "dump_engine_health",
-                lambda req: self.ec_dispatch.engine_health(),
-                "EC engine health state machine: breaker state, probe "
-                "backoff, failure history, failover totals",
-            )
+        a.register(
+            "dump_ec_dispatch",
+            lambda req: self.ec_dispatch.dump(),
+            "EC microbatch dispatcher: open batches, flush reasons, "
+            "pad waste, observed bucket table",
+        )
+        a.register(
+            "dump_launch_history",
+            lambda req: self.ec_dispatch.flight.dump(),
+            "device-launch flight recorder: the last N launches "
+            "(lane, batch key, QoS class, queue-wait vs device "
+            "wall, slowest member op's trace id)",
+        )
+        a.register(
+            "dump_engine_health",
+            lambda req: self.ec_dispatch.engine_health(),
+            "EC engine health state machine: breaker state, probe "
+            "backoff, failure history, failover totals",
+        )
         a.register(
             "dump_op_pq_state",
             lambda req: self.scheduler.dump(),
@@ -1065,13 +1022,12 @@ class OSD(Dispatcher):
         for t in list(self._tasks):
             if t is not me:  # a tracked task calling stop() must finish it
                 t.cancel()
-        if self.ec_dispatch is not None:
-            # Task.cancel() above only MARKS the op tasks — yield once
-            # so the cancellations actually land on their awaited
-            # futures, then the flush below drops them instead of
-            # launching a full device batch for doomed ops
-            await asyncio.sleep(0)
-            await self.ec_dispatch.stop()
+        # Task.cancel() above only MARKS the op tasks — yield once so
+        # the cancellations actually land on their awaited futures,
+        # then the flush below drops them instead of launching a full
+        # device batch for doomed ops
+        await asyncio.sleep(0)
+        await self.ec_dispatch.stop()
         if self._admin is not None:
             await self._admin.stop()
             self._admin = None
@@ -1120,8 +1076,7 @@ class OSD(Dispatcher):
             # remote lane's in-flight batches, beacons update the
             # routing health (TRIPPED/saturated -> local lanes, no
             # timeout chain)
-            if self.accel_client is not None:
-                self.accel_client.handle(msg, conn)
+            self.accel_client.handle(msg, conn)
         elif isinstance(msg, messages.MOSDRepOp):
             self._handle_rep_op(conn, msg)
         elif isinstance(msg, messages.MOSDRepOpReply):
@@ -1153,12 +1108,11 @@ class OSD(Dispatcher):
             return
         if conn is self._mgr_conn:
             self._mgr_conn = None
-        if self.accel_client is not None:
-            # the accelerator link died: fail the remote lane's
-            # in-flight batches NOW (they replay on the local fallback
-            # without waiting out the RPC deadline) and mark the remote
-            # unreachable so new batches route local immediately
-            self.accel_client.on_reset(conn)
+        # the accelerator link died: fail the remote lane's in-flight
+        # batches NOW (they replay on the local fallback without
+        # waiting out the RPC deadline) and mark the remote unreachable
+        # so new batches route local immediately
+        self.accel_client.on_reset(conn)
         # a dead client's watches die with its connection (reference:
         # Watch.cc handle_watch_timeout; lingers re-register on reconnect)
         for key, table in list(self._watchers.items()):
@@ -1219,11 +1173,10 @@ class OSD(Dispatcher):
         self.osdmap = m
         self.perf.get("churn").inc("maps_applied")
         self._codecs.clear()  # pools/profiles may have changed
-        if self.accel_client is not None:
-            # the accelerator fleet rides the map (ISSUE 11): a mon
-            # markdown reaches this router on the same push that
-            # carries any other map change — one push, no side channel
-            self.accel_client.apply_map(m.accelmap)
+        # the accelerator fleet rides the map (ISSUE 11): a mon markdown
+        # reaches this router on the same push that carries any other
+        # map change — one push, no side channel
+        self.accel_client.apply_map(m.accelmap)
         try:
             self._note_intervals(old, m)
         except Exception:
@@ -1373,23 +1326,22 @@ class OSD(Dispatcher):
             # included: an op the client must replay is exactly the
             # op whose waterfall the operator will want
             return "error"
-        if self.ec_dispatch is not None:
-            # anomaly evidence from the launch that carried this trace
-            # (ops/device_trace.py FlightRecorder): an engine fault, a
-            # failover-served batch, or an accelerator that answered
-            # from ITS fallback — correct bytes, but a re-routed path
-            # worth a waterfall.  O(flight ring) per op; the ring is
-            # empty on pure-replicated paths.
-            try:
-                rec = self.ec_dispatch.flight.lookup(msg.trace)
-            except Exception:  # pragma: no cover - observability only
-                rec = None
-            if rec is not None and (
-                rec.get("error") or rec.get("origin")
-                or rec.get("served") == "fallback"
-                or rec.get("remote_served") == "fallback"
-            ):
-                return "replay"
+        # anomaly evidence from the launch that carried this trace
+        # (ops/device_trace.py FlightRecorder): an engine fault, a
+        # failover-served batch, or an accelerator that answered from
+        # ITS fallback — correct bytes, but a re-routed path worth a
+        # waterfall.  O(flight ring) per op; the ring is empty on
+        # pure-replicated paths.
+        try:
+            rec = self.ec_dispatch.flight.lookup(msg.trace)
+        except Exception:  # pragma: no cover - observability only
+            rec = None
+        if rec is not None and (
+            rec.get("error") or rec.get("origin")
+            or rec.get("served") == "fallback"
+            or rec.get("remote_served") == "fallback"
+        ):
+            return "replay"
         return "baseline" if sampled else None
 
     def _waterfall_spans(self, conn: Connection, msg: messages.MOSDOp,
@@ -1483,12 +1435,10 @@ class OSD(Dispatcher):
             spans.append({"hop": "execute", "t0": td,
                           "dur": max(0.0, now - td),
                           "entity": self.name})
-            rec = None
-            if self.ec_dispatch is not None:
-                try:
-                    rec = self.ec_dispatch.flight.lookup(trace)
-                except Exception:  # pragma: no cover - observability only
-                    rec = None
+            try:
+                rec = self.ec_dispatch.flight.lookup(trace)
+            except Exception:  # pragma: no cover - observability only
+                rec = None
             if rec:
                 parent = span_id_for(trace, self.name, "execute")
                 cursor = now
@@ -1658,21 +1608,20 @@ class OSD(Dispatcher):
                     ptr.inc("kept")
                     ptr.inc("kept_" + keep)
                     launch = None
-                    if self.ec_dispatch is not None:
-                        # launch-record linkage: the flight ring entry
-                        # that served this op, so `trace show` can name
-                        # the lane/engine behind a replay-kept trace
-                        try:
-                            rec = self.ec_dispatch.flight.lookup(msg.trace)
-                        except Exception:  # pragma: no cover
-                            rec = None
-                        if rec is not None:
-                            launch = {
-                                k: rec.get(k)
-                                for k in ("seq", "served", "origin",
-                                          "error", "remote_served")
-                                if rec.get(k) is not None
-                            }
+                    # launch-record linkage: the flight ring entry that
+                    # served this op, so `trace show` can name the
+                    # lane/engine behind a replay-kept trace
+                    try:
+                        rec = self.ec_dispatch.flight.lookup(msg.trace)
+                    except Exception:  # pragma: no cover
+                        rec = None
+                    if rec is not None:
+                        launch = {
+                            k: rec.get(k)
+                            for k in ("seq", "served", "origin",
+                                      "error", "remote_served")
+                            if rec.get(k) is not None
+                        }
                     self._pending_traces.append({
                         "trace": msg.trace,
                         "client": msg.client,
@@ -2400,63 +2349,27 @@ class OSD(Dispatcher):
             "opname": opname, "attr_ops": attr_ops,
         }
 
-    # -- EC math routing: device-mesh engine vs host path --------------------
-    @contextlib.contextmanager
-    def _ec_timed(self, op: str, nbytes: int, mesh: bool,
-                  account: bool = True):
-        """Shared kernel-boundary instrumentation for the encode/decode
-        routers: one trace span + wall-time avg + per-engine GB/s gauge
-        (the number bench.py's tpu_stack_gbps tracks) — one definition
-        so the two paths cannot drift.  ``account=False`` on the
-        dispatcher route: the op-level wall time there includes queue
-        wait plus the whole shared batch, so feeding it to the
-        device-wall-time avg/histogram/gauge would inflate every one of
-        them by the coalescing window (and N-fold for the batch) — the
-        dispatcher records those from its own per-launch time instead;
-        only the trace span (genuinely per-op) remains here."""
-        pec = self.perf.get("ec")
-        t0 = time.perf_counter()
-        with _trace_ec.span(f"ec_{op}", nbytes=nbytes,
-                            engine="mesh" if mesh else "host"):
-            yield
-        if not account:
-            return
-        ec_util.account_ec_call(pec, op, nbytes,
-                                time.perf_counter() - t0, mesh=mesh)
-
+    # -- EC math routing: every op through the dispatcher ------------------
     async def _ec_encode_bufs(self, sinfo, codec, buf, *,
                               klass: str = "client",
                               ) -> dict[int, np.ndarray]:
-        """Encode router (VERDICT r4 #2, ISSUE 8): with
-        ``osd_ec_dispatch`` on, everything goes through the cross-op
-        microbatch dispatcher (coalesced launches in a worker thread,
-        so heartbeat/messenger/op-tracker tasks are never frozen
-        behind a device call) — with ``osd_ec_mesh`` also on, matrix
-        codecs take its MESH LANE, where the k+m shard rows are
+        """Encode router (VERDICT r4 #2, ISSUE 8): every encode goes
+        through the cross-op microbatch dispatcher (coalesced launches
+        in a worker thread, so heartbeat/messenger/op-tracker tasks are
+        never frozen behind a device call) — with ``osd_ec_mesh`` on,
+        matrix codecs take its MESH LANE, where the k+m shard rows are
         computed BY the mesh (shard rows on mesh rows,
         reference:src/osd/ECBackend.cc:1902-1926 as device placement).
-        Dispatcher off keeps the old direct routes (mesh per-op, else
-        inline ec_util).  Bytes are identical on every route (pinned
-        by tests/test_mesh_datapath.py, tests/test_mesh_dispatch.py
-        and tests/test_ec_dispatch.py)."""
-        dispatched = self.ec_dispatch is not None
-        # with the dispatcher on, the mesh is one of ITS lanes (ISSUE
-        # 8): coalescing/QoS/deadline/failover apply to mesh traffic;
-        # the direct route survives only for osd_ec_dispatch=false
-        mesh = (
-            self.ec_dispatch.mesh_route(sinfo, codec) if dispatched
-            else self.ec_mesh is not None and self.ec_mesh.supports(codec)
-        )
-        with self._ec_timed("encode", len(buf), mesh,
-                            account=not dispatched):
-            if dispatched:
-                return await self.ec_dispatch.encode(
-                    sinfo, codec, buf, klass=klass
-                )
-            if mesh:
-                self.perf.get("ec").inc("mesh_encode_calls")
-                return self.ec_mesh.encode(sinfo, codec, buf)
-            return ec_util.encode(sinfo, codec, buf)
+        The trace span is per op; the dispatcher feeds the ``ec``
+        device-wall-time family once per launch.  Bytes are identical
+        on every lane (pinned by tests/test_mesh_datapath.py,
+        tests/test_mesh_dispatch.py and tests/test_ec_dispatch.py)."""
+        mesh = self.ec_dispatch.mesh_route(sinfo, codec)
+        with _trace_ec.span("ec_encode", nbytes=len(buf),
+                            engine="mesh" if mesh else "host"):
+            return await self.ec_dispatch.encode(
+                sinfo, codec, buf, klass=klass
+            )
 
     async def _ec_decode_concat(self, sinfo, codec, chunks, *,
                                 klass: str = "client",
@@ -2464,33 +2377,20 @@ class OSD(Dispatcher):
                                 ) -> bytes:
         """Reconstruct router: missing rows rebuilt via the mesh's ICI
         all-gather (reference:src/osd/ECBackend.cc:2187 as one
-        collective) when the engine applies; host decodes ride the
+        collective) when the mesh lane applies; every decode rides the
         microbatch dispatcher like encodes.  ``locality`` carries the
         surviving shards' OSD locality labels (crush host names) so
         the accel router can prefer the accelerator co-located with
         the survivor bytes (ISSUE 11 shard-locality decode)."""
         k = codec.get_data_chunk_count()
         missing = any(r not in chunks for r in range(k))
-        dispatched = self.ec_dispatch is not None
-        mesh = (
-            self.ec_dispatch.mesh_route(sinfo, codec, missing=missing)
-            if dispatched
-            else (self.ec_mesh is not None
-                  and self.ec_mesh.supports(codec)
-                  and missing)
-        )
+        mesh = self.ec_dispatch.mesh_route(sinfo, codec, missing=missing)
         nbytes = sum(int(c.size) for c in chunks.values())
-        with self._ec_timed("decode", nbytes, mesh,
-                            account=not dispatched):
-            if dispatched:
-                return await self.ec_dispatch.decode_concat(
-                    sinfo, codec, chunks, klass=klass,
-                    locality=locality,
-                )
-            if mesh:
-                self.perf.get("ec").inc("mesh_decode_calls")
-                return self.ec_mesh.decode_concat(sinfo, codec, chunks)
-            return ec_util.decode_concat(sinfo, codec, chunks)
+        with _trace_ec.span("ec_decode", nbytes=nbytes,
+                            engine="mesh" if mesh else "host"):
+            return await self.ec_dispatch.decode_concat(
+                sinfo, codec, chunks, klass=klass, locality=locality,
+            )
 
     async def _ec_mutate_execute(
         self, pg: PGid, pool: Pool, acting: list[int], oid: str,
@@ -4313,14 +4213,12 @@ class OSD(Dispatcher):
         New slow ops are clog'd once (edge-triggered) like the
         reference's '%d slow requests' cluster-log warnings."""
         self.scheduler.refresh_gauges()  # qos share-attainment gauges
-        if self.ec_supervisor is not None:
-            # engine_state must survive an admin `perf reset` — a
-            # zeroed gauge would clear ACCEL_DEGRADED while TRIPPED
-            self.ec_supervisor.refresh_gauge()
-        if self.accel_client is not None:
-            # same rule for remote_unreachable: a perf reset must not
-            # silently clear ACCEL_UNREACHABLE while the remote is down
-            self.accel_client.refresh_gauges()
+        # engine_state must survive an admin `perf reset` — a zeroed
+        # gauge would clear ACCEL_DEGRADED while TRIPPED
+        self.ec_supervisor.refresh_gauge()
+        # same rule for remote_unreachable: a perf reset must not
+        # silently clear ACCEL_UNREACHABLE while the remote is down
+        self.accel_client.refresh_gauges()
         self._pull_device_trace_totals()
         slow = self.op_tracker.slow_ops(self.config.osd_op_complaint_time)
         posd = self.perf.get("osd")

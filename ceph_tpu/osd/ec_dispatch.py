@@ -27,22 +27,20 @@ Three mechanisms, composed:
   the next power of two before the device call (pad rows sliced off on
   the way out), so the jit cache holds O(log max_S) entries per codec
   instead of one per distinct size.  Pad waste is tracked
-  (``ec.dispatch_pad_stripes``/``_bytes``).  The native C engine has no
-  jit cache, so bucketing is skipped there (padding would be pure
-  waste).
+  (``ec.dispatch_pad_stripes``/``_bytes``).
 - **event-loop liberation** — the batched device call runs in a
   ``ThreadPoolExecutor`` via ``run_in_executor``, so heartbeat,
   messenger, and op-tracker tasks keep ticking during a long encode
   instead of freezing behind a synchronous device call.
 
-The native C engine opts out of coalescing entirely (requests still run
-in the worker pool): it has no launch or compile overhead to amortize,
-and measured on-host, per-op buffers are cache-resident while a stacked
-multi-op pass goes DRAM-bound — coalescing there trades a fast path for
-a slow one.  The gates are ec_util's shared
-``native_encode_path``/``native_decode_path`` predicates, the same
-conditions the encode/decode stacks route on, so the lanes cannot
-drift.
+Every EC op of the OSD goes through this dispatcher, on one of four
+lanes chosen per request by :meth:`ECDispatcher.encode` /
+:meth:`ECDispatcher.decode_concat`: **remote** (the accelerator fleet,
+an operator opt-in), **mesh** (the multi-chip engine, ``osd_ec_mesh``),
+**device** (this process's jax backend through ``ec_util`` — the same
+program on a TPU and on the CPU backend the tests run), and **host
+fallback** (the failover engine, per op, while the breaker is
+TRIPPED).  The first three coalesce; the last does not.
 
 A fourth mechanism is the **mesh lane** (ISSUE 8 — the multi-chip
 engine as a first-class dispatcher lane, not a bypass):
@@ -110,7 +108,7 @@ through perf dump -> mgr prometheus like every other key), the
 ``launch_deadline_timeouts`` counters for the fault domain, the
 KernelProfiler sees the bucketed shapes at the codec boundary,
 ``dump_ec_dispatch`` on the admin socket serves :meth:`ECDispatcher.dump`,
-and every launch (batched, native-direct, fallback-direct) lands in the
+and every launch (batched or fallback-direct) lands in the
 :class:`~ceph_tpu.ops.device_trace.FlightRecorder` ring — lane, batch
 key, QoS class, queue-wait vs device wall, slowest member trace id —
 served by ``dump_launch_history`` and consulted by the SLOW_OPS dump
@@ -289,7 +287,7 @@ class ECDispatcher:
         # dump()-side totals, independent of the perf wiring
         self._totals = {
             "batches": 0, "ops": 0, "stripes": 0, "cancelled": 0,
-            "pad_stripes": 0, "pad_bytes": 0, "native_direct": 0,
+            "pad_stripes": 0, "pad_bytes": 0,
             "failovers": 0, "replayed_ops": 0, "fallback_direct": 0,
             "deadline_timeouts": 0,
             "flush": {"size": 0, "window": 0, "stop": 0},
@@ -337,8 +335,8 @@ class ECDispatcher:
         OSD peer, so the flight recorder can show which clients shared
         a launch)."""
         if client is None:
-            # tenant attribution (ISSUE 16): the direct lanes bypass
-            # _Op, so capture the contextvar here too
+            # tenant attribution (ISSUE 16): the fallback-direct lane
+            # bypasses _Op, so capture the contextvar here too
             client = current_client.get()
         buf = as_u8(data)
         if buf.size % sinfo.stripe_width != 0:
@@ -363,9 +361,8 @@ class ECDispatcher:
         # lane — its whole point is taking the device math off this
         # host; its OWN breaker beacon gates it, not the local
         # supervisor.  Below it, the mesh (osd_ec_mesh) outranks the
-        # native C engine, exactly as the old router ordered its
-        # routes; the native lane outranks the single-device jax lane
-        # on CPU hosts as before
+        # single-device lane; a TRIPPED local breaker sends both to the
+        # host fallback
         if self._remote is not None and self._remote.routes(codec):
             key = ("enc", "remote", None, klass, id(codec),
                    sinfo.stripe_width, sinfo.chunk_size)
@@ -375,13 +372,6 @@ class ECDispatcher:
         lane = "mesh" if (
             self._mesh is not None and self._mesh.routes(sinfo, codec)
         ) else "device"
-        if lane != "mesh" and ec_util.native_encode_path(sinfo, codec):
-            # no launch/compile overhead to amortize on the C engine —
-            # keep per-op (cache-resident) calls, just off the loop
-            return await self._run_native_direct(
-                ec_util.encode, sinfo, codec, buf, "encode", buf.size,
-                klass=klass, client=client,
-            )
         if self._supervisor is not None and not self._supervisor.device_ok():
             # breaker TRIPPED/PROBING: the device engine — mesh slice
             # included, it is the same accelerator fault domain — is
@@ -451,17 +441,12 @@ class ECDispatcher:
                                       locality=locality)
         # the mesh lane only earns its keep when rows are MISSING (the
         # ICI all-gather reconstruct); a plain concat read stays on the
-        # device/native lanes — the same gate the old router applied
+        # device lane
         lane = "mesh" if (
             self._mesh is not None
             and self._mesh.routes(sinfo, codec)
             and missing
         ) else "device"
-        if lane != "mesh" and ec_util.native_decode_path(codec, shard_len):
-            return await self._run_native_direct(
-                ec_util.decode_concat, sinfo, codec, arrs, "decode",
-                shard_len * len(arrs), klass=klass, client=client,
-            )
         if self._supervisor is not None and not self._supervisor.device_ok():
             return await self._run_fallback_direct(
                 ec_util.decode_concat_fallback, sinfo, codec, arrs,
@@ -596,28 +581,21 @@ class ECDispatcher:
 
     # -- queueing ------------------------------------------------------------
 
-    async def _run_direct(self, fn, sinfo, codec, payload, op: str,
-                          nbytes: int, totals_key: str,
-                          perf_key: str | None = None,
-                          klass: str = "client",
-                          client: str | None = None):
-        """Per-op call in the worker pool (event-loop liberation
-        without coalescing) — shared by the native C lane and the
-        host-fallback lane (the serving path while the device engine
-        is TRIPPED).  The call is timed in-worker: pool queue wait must
-        not read as device time in the gauges/histograms under load —
-        and whichever engine serves, its time feeds the same gauges
-        (the daemon's op-level timer includes executor-hop wait, so it
-        no longer feeds them on the dispatch route).  Direct calls are
-        launches too: they ride the flight recorder (lane =
-        native_direct/fallback_direct, one-op "batch"), so a slow op
+    async def _run_fallback_direct(self, fn, sinfo, codec, payload,
+                                   op: str, nbytes: int,
+                                   klass: str = "client",
+                                   client: str | None = None):
+        """Per-op host-fallback call in the worker pool — the serving
+        lane while the device engine is TRIPPED (event-loop liberation
+        without coalescing).  The call is timed in-worker, so pool queue
+        wait never reads as engine time in the gauges/histograms under
+        load.  Direct calls are launches too: they ride the flight
+        recorder (lane = fallback_direct, one-op "batch"), so a slow op
         served off-device still names what carried it."""
-        self._totals[totals_key] = self._totals.get(totals_key, 0) + 1
-        if self._perf is not None and perf_key is not None:
-            self._perf.inc(perf_key)
+        self._totals["fallback_direct"] += 1
         loop = asyncio.get_running_loop()
         flight = self.flight.begin(
-            lane=totals_key, kind="enc" if op == "encode" else "dec",
+            lane="fallback_direct", kind="enc" if op == "encode" else "dec",
             klass=klass, ops=1, stripes=None,
             stripe_width=sinfo.stripe_width,
             chunk_size=sinfo.chunk_size, queue_wait_s=0.0,
@@ -640,28 +618,13 @@ class ECDispatcher:
             # launches forever
             self.flight.end(flight, served="error", error=repr(e))
             raise
-        self.flight.end(flight, device_wall_s=dt, served=totals_key)
+        self.flight.end(flight, device_wall_s=dt, served="fallback_direct")
         if self._perf is not None:
             try:
                 ec_util.account_ec_call(self._perf, op, nbytes, dt)
             except Exception:  # swallow-ok: observability is best-effort
                 pass
         return out
-
-    def _run_native_direct(self, fn, sinfo, codec, payload, op: str,
-                           nbytes: int, klass: str = "client",
-                           client: str | None = None):
-        return self._run_direct(fn, sinfo, codec, payload, op, nbytes,
-                                "native_direct",
-                                perf_key="dispatch_native_direct",
-                                klass=klass, client=client)
-
-    def _run_fallback_direct(self, fn, sinfo, codec, payload, op: str,
-                             nbytes: int, klass: str = "client",
-                             client: str | None = None):
-        return self._run_direct(fn, sinfo, codec, payload, op, nbytes,
-                                "fallback_direct", klass=klass,
-                                client=client)
 
     async def _submit(self, key: tuple, kind: str, codec, sinfo,
                       payload, stripes: int, *, lane: str = "device",
@@ -1169,8 +1132,7 @@ class ECDispatcher:
     # -- the batched launch (executor thread) --------------------------------
 
     def _pad_for(self, b: _Batch, total_stripes: int) -> int:
-        """Zero stripes to add (only jit-path codecs reach a batch —
-        the native engine took the direct lane in encode/decode).  The
+        """Zero stripes to add (the host replay pads nothing).  The
         mesh lane always pads to its alignment quantum (shards must
         stay balanced across the slice even with bucketing disabled);
         bucketing then rounds the per-chip stripe count to a power of

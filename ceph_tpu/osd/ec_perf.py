@@ -68,8 +68,7 @@ def create_ec_perf(perf):
                     "chunk crcs computed for commits' stripe-hash tables")
     pec.add_counter("hinfo_calls",
                     "native crc calls those chunk crcs took")
-    # cross-op microbatch dispatcher (osd_ec_dispatch; see
-    # osd/ec_dispatch.py): coalesced-launch + bucketing evidence
+    # cross-op microbatch dispatcher (see osd/ec_dispatch.py): coalesced-launch + bucketing evidence
     pec.add_counter("dispatch_batches", "coalesced device launches")
     pec.add_counter("dispatch_ops",
                     "encode/decode requests served by coalesced launches")
@@ -85,10 +84,6 @@ def create_ec_perf(perf):
                     "zero stripes added by shape bucketing")
     pec.add_counter("dispatch_pad_bytes",
                     "bucket pad waste in bytes")
-    pec.add_counter("dispatch_native_direct",
-                    "per-op calls routed straight to the native C "
-                    "engine in the worker pool (no coalescing win "
-                    "there — see ec_dispatch)")
     pec.add_avg("dispatch_occupancy",
                 "batch stripes / flush threshold at launch")
     pec.add_histogram(
@@ -98,8 +93,8 @@ def create_ec_perf(perf):
     )
     # per-lane split of the dispatcher evidence (ISSUE 8
     # satellite): pad waste / occupancy / batch sizes attributable
-    # per route (native-direct has its own counter above — no
-    # batching there, so no occupancy/pad series)
+    # per route (the per-op fallback-direct lane batches nothing, so
+    # it has no occupancy/pad series)
     pec.add_counter("dispatch_batches_device",
                     "coalesced launches on the single-device lane")
     pec.add_counter("dispatch_batches_mesh",

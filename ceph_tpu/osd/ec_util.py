@@ -92,43 +92,13 @@ class StripeInfo:
 
 # -- batched stripe math -----------------------------------------------------
 
-def _native_matrix_engine(ec_impl) -> bool:
-    """The native C GF engine applies: a CPU-host jax backend, a plain
-    w=8 matrix codec, and a loadable native library (one shared gate —
-    native.host_engine_active)."""
-    from ..models.matrix_codec import MatrixErasureCode
-
-    return (
-        type(ec_impl) is MatrixErasureCode
-        and ec_impl.w == 8
-        and native.host_engine_active()
-    )
-
-
-def native_encode_path(sinfo: StripeInfo, ec_impl) -> bool:
-    """Will :func:`encode` actually take the native C branch for this
-    geometry?  ONE predicate shared with the microbatch dispatcher's
-    per-op direct lane, so the routing gates cannot drift (the branch
-    below additionally needs ``cs % 8 == 0``)."""
-    return sinfo.chunk_size % 8 == 0 and _native_matrix_engine(ec_impl)
-
-
-def native_decode_path(ec_impl, shard_len: int) -> bool:
-    """Will the codec's decode take the native C branch for shard
-    buffers of ``shard_len`` bytes?  Mirrors the gate in
-    MatrixErasureCode.decode_chunks (w=8, last dim % 8, native engine);
-    shared with the dispatcher for the same no-drift reason."""
-    return shard_len % 8 == 0 and _native_matrix_engine(ec_impl)
-
-
 def account_ec_call(pec, op: str, nbytes: int, seconds: float,
                     *, mesh: bool = False) -> None:
     """THE definition of the ``ec.{encode,decode}`` device-wall-time
     feed — time avg + (size x latency) histogram + per-engine GB/s
-    gauge — shared by the OSD router (inline/direct-mesh routes), the
-    microbatch dispatcher's batch launches (``mesh=True`` on its mesh
-    lane, feeding the ``mesh_*_gbps`` gauges per launch), and its
-    native direct lane, so the call sites cannot drift."""
+    gauge — fed by the microbatch dispatcher once per launch and once
+    per fallback-direct call (``mesh=True`` on its mesh lane, feeding
+    the ``mesh_*_gbps`` gauges)."""
     pec.observe(f"{op}_time", seconds)
     pec.hist(f"{op}_time_histogram", nbytes, seconds)
     if seconds > 0:
@@ -208,41 +178,9 @@ def encode(
     cs = sinfo.chunk_size
     # [S, k, cs] -> [k, S*cs]: shard i's buffer is its chunk from each stripe
     # in order, exactly the reference's per-stripe append layout.
-    #
-    # Engine routing (r4 Weak #3 — the stack must not pay ~3x over the
-    # raw kernel): on a CPU host the GF matmul runs in the native C
-    # engine (the gf-complete/ISA-L class — no host<->jax buffer copies,
-    # no dispatch), exactly as the reference routes to ISA-L on CPU; on
-    # an accelerator backend the fused device program keeps all layout
-    # work on device.  Parity bytes are identical on every path (the GF
-    # algebra is exact; tests pin all engines to the numpy oracle).
-    if cs % 8 == 0 and _native_matrix_engine(ec_impl):
-        # one C pass produces shard rows + parity (transpose and matmul
-        # fused — no second read of the input)
-        from ..ops.profiler import profiler
-
-        m = ec_impl.get_coding_chunk_count()
-        # the OSD's CPU-host hot path bypasses the jax codec entries, so
-        # it must report into the kernel profiler here or the daemon's
-        # dump_kernel_profile is empty exactly where the stack runs;
-        # no jit cache on the C engine -> every call is steady-state.
-        # The matrix key is built once at codec construction (_mkey) —
-        # re-serializing matrix.tobytes() per op was hot-path waste.
-        # The C pass performs the SAME stripe->shard layout memcpy the
-        # jax paths do on host — it must hit the copy audit identically
-        # or the <=1x budget gate would depend on engine routing.
-        note_copy("ec_gather", buf.size)
-        with profiler().timed(
-            "native_stripes_encode",
-            (ec_impl._mkey, S, cs),
-            nbytes=buf.size, shape=(S, k, cs), compiled=False,
-        ):
-            out_arr = native.encode_stripes(ec_impl.matrix, buf, S, cs)
-        return {i: out_arr[i] for i in range(k + m)}
     encs = getattr(ec_impl, "encode_shards_u32", None)
     if (
         encs is not None and cs % 4 == 0 and buf.ctypes.data % 4 == 0
-        and not native.host_engine_active()
     ):
         # fully-fused device path: the input is a FREE u32 view of the
         # client buffer; transpose + matmul + concat run in one jitted

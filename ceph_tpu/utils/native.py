@@ -4,10 +4,10 @@ Builds on first use (g++ -O3 -march=native) into native/build/, under a
 name keyed on the source, the command and the CPU the compiler targets
 (:func:`build_so`), so a copied tree never loads a binary built for
 another host.  This is
-the host-side codec used as the CPU baseline in bench.py and as an
-independent oracle for the TPU kernels (both implement the same doubling
-scheme, so parity bytes must agree exactly with each other and with the
-numpy table-based oracle).
+the host-side codec used as the CPU baseline in bench.py, as the engine
+of the failover replay, and as an independent oracle for the TPU kernels
+(both implement the same doubling scheme, so parity bytes must agree
+exactly with each other and with the numpy table-based oracle).
 """
 
 from __future__ import annotations
@@ -85,17 +85,6 @@ def lib() -> ctypes.CDLL:
                 ctypes.c_int64,
             ]
             _lib.gf16_encode_flat.argtypes = _lib.gf8_encode_flat.argtypes
-            _lib.gf8_encode_stripes.argtypes = [
-                ctypes.POINTER(ctypes.c_int), ctypes.c_int, ctypes.c_int,
-                ctypes.c_int64, ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(ctypes.c_uint8),
-            ]
-            _lib.gf8_encode_stripes_block.argtypes = [
-                ctypes.POINTER(ctypes.c_int), ctypes.c_int, ctypes.c_int,
-                ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-                ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(ctypes.c_uint8),
-            ]
             _lib.gf8_mul_region.argtypes = [
                 ctypes.c_uint8, ctypes.POINTER(ctypes.c_uint8),
                 ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64,
@@ -143,165 +132,21 @@ _HOST_ACTIVE: bool | None = None
 
 
 def host_engine_active() -> bool:
-    """True when jax's default backend is the host CPU and this native
-    GF engine is loadable — the ONE routing gate shared by the encode
-    stack (osd/ec_util) and the codec decode path (models/matrix_codec).
-    A backend that fails to start raises here; only a native library
-    that cannot be built reads as "not active"."""
+    """True when this native library builds and loads, cached in
+    ``_HOST_ACTIVE``.  It gates one thing: whether the codec's host
+    oracle (``MatrixErasureCode._host_matmul``, the failover replay's
+    engine) runs the native matmul or the numpy one; every EC op of
+    the served path goes through the dispatcher's lanes whatever the
+    backend.  The name is kept because the benchmark's served-path
+    fixture reads and sets ``_HOST_ACTIVE``."""
     global _HOST_ACTIVE
     if _HOST_ACTIVE is None:
-        import jax
-
-        active = jax.default_backend() == "cpu"
-        if active:
-            try:
-                lib()
-            except (OSError, subprocess.CalledProcessError):
-                active = False  # no compiler here: the jax lane serves
-        _HOST_ACTIVE = active
+        try:
+            lib()
+            _HOST_ACTIVE = True
+        except (OSError, subprocess.CalledProcessError):
+            _HOST_ACTIVE = False  # no compiler here: numpy serves
     return _HOST_ACTIVE
-
-
-_stripe_pool = None  # lazy ThreadPoolExecutor for the parallel encode
-_PAR_MIN_BYTES = 1 << 21  # below 2 MiB the fork/join overhead wins
-_stripe_workers_default = 1  # set by calibrate_stripe_workers()
-
-
-def stripe_workers() -> int:
-    """Worker threads for the parallel stripe encode (ctypes releases
-    the GIL around the C call, so blocks really run in parallel).
-    CEPH_TPU_NATIVE_WORKERS overrides (1 disables); otherwise the
-    calibrated default — 1 until :func:`calibrate_stripe_workers` has
-    proven parallelism wins on THIS host (container-throttled or
-    single-channel boxes go memory-bound and lose to the serial pass,
-    measured: 2 workers = 0.85x on a 2-vCPU cgroup)."""
-    import os
-
-    env = os.environ.get("CEPH_TPU_NATIVE_WORKERS")
-    if env:
-        try:
-            return max(1, int(env))
-        except ValueError:
-            pass
-    return _stripe_workers_default
-
-
-def calibrate_stripe_workers(budget_s: float = 1.0) -> dict:
-    """Race the serial vs all-cores stripe encode on a synthetic RS(8,3)
-    batch and lock the winner in as the process default (the ISA-L
-    cpu-dispatch idea, done by measurement instead of cpuid).  Called by
-    the bench stack child and available to daemons at boot; returns the
-    verdict dict for logs/round JSON."""
-    global _stripe_workers_default
-    import os
-    import time as _time
-
-    ncpu = max(1, os.cpu_count() or 1)
-    verdict = {"cpus": ncpu, "workers": stripe_workers(),
-               "serial_gbps": None, "parallel_gbps": None}
-    pinned = os.environ.get("CEPH_TPU_NATIVE_WORKERS")
-    if pinned:
-        # an explicit operator pin ALWAYS wins: measuring would both
-        # be pointless and (worse) clobber the override mid-race for
-        # any concurrent encode reading stripe_workers()
-        verdict["pinned"] = pinned
-        return verdict
-    if ncpu == 1:
-        return verdict
-    matrix = rs_vandermonde_matrix(8, 3, 8)
-    S, cs, k = 256, 2048, 8
-    buf = np.arange(S * k * cs, dtype=np.uint32).astype(np.uint8)
-
-    def rate(workers: int) -> float:
-        # flip only the process default (no env mutation): a concurrent
-        # encode may take either lane mid-calibration — both are
-        # correct, and the final default is restored below either way
-        global _stripe_workers_default
-        _stripe_workers_default = workers
-        try:
-            encode_stripes(matrix, buf, S, cs)  # warm (pool spin-up)
-            t0 = _time.perf_counter()
-            n = 0
-            while _time.perf_counter() - t0 < budget_s / 2:
-                encode_stripes(matrix, buf, S, cs)
-                n += 1
-            return buf.size * n / (_time.perf_counter() - t0)
-        finally:
-            _stripe_workers_default = 1
-    try:
-        ser = rate(1)
-        par = rate(ncpu)
-    except Exception:
-        return verdict
-    verdict["serial_gbps"] = round(ser / 1e9, 3)
-    verdict["parallel_gbps"] = round(par / 1e9, 3)
-    if par > ser * 1.1:  # demand a real win before going parallel
-        _stripe_workers_default = ncpu
-        verdict["workers"] = ncpu
-    return verdict
-
-
-def encode_stripes(
-    matrix: np.ndarray, buf: np.ndarray, S: int, cs: int
-) -> np.ndarray:
-    """Fused stripe-layout encode: ``buf`` is the client's [S*k*cs] byte
-    stream; returns [k+m, S*cs] whose rows are the per-shard buffers
-    (data rows laid out + parity), produced in ONE pass over the input
-    (the codec stack's transpose and matmul fused — see
-    native/ec_cpu.cc gf8_encode_stripes).
-
-    Large batches split their stripe range across host cores: each
-    worker runs the STRIDED C body (gf8_encode_stripes_block) over a
-    disjoint stripe range of the one shared output, so the parallel
-    pass writes the same bytes as the serial pass with zero extra
-    allocation or copy — stripes are independent in the GF algebra."""
-    L = lib()
-    matrix = np.ascontiguousarray(matrix, dtype=np.int32)
-    m, k = matrix.shape
-    buf = np.ascontiguousarray(buf.reshape(-1))
-    assert buf.size == S * k * cs and cs % 8 == 0
-    out = np.empty((k + m, S * cs), dtype=np.uint8)
-    mptr = matrix.ctypes.data_as(ctypes.POINTER(ctypes.c_int))
-    workers = stripe_workers()
-    if workers <= 1 or S < 2 * workers or buf.size < _PAR_MIN_BYTES:
-        L.gf8_encode_stripes(mptr, k, m, S, cs, _u8ptr(buf), _u8ptr(out))
-        return out
-    global _stripe_pool
-    if _stripe_pool is None:
-        from concurrent.futures import ThreadPoolExecutor
-
-        with _lock:
-            if _stripe_pool is None:
-                # sized to the HOST, not to the current worker setting:
-                # the pool is created once and outlives calibration /
-                # env changes, so a transient low setting must not
-                # permanently undersize it
-                import os as _os
-
-                _stripe_pool = ThreadPoolExecutor(
-                    max_workers=max(2, _os.cpu_count() or 2),
-                    thread_name_prefix="gf-stripes",
-                )
-    shard_len = S * cs
-    step = -(-S // workers)
-    in_addr = buf.ctypes.data
-    out_ptr = _u8ptr(out)
-
-    def run_block(s0: int) -> None:
-        nS = min(step, S - s0)
-        in_ptr = ctypes.cast(
-            in_addr + s0 * k * cs, ctypes.POINTER(ctypes.c_uint8)
-        )
-        L.gf8_encode_stripes_block(
-            mptr, k, m, s0, nS, cs, shard_len, in_ptr, out_ptr
-        )
-
-    futs = [
-        _stripe_pool.submit(run_block, s0) for s0 in range(0, S, step)
-    ]
-    for f in futs:
-        f.result()  # propagate any worker failure
-    return out
 
 
 def crc32c(crc: int, data: bytes | np.ndarray) -> int:

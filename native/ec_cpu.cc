@@ -173,62 +173,6 @@ void gf8_encode_flat(const int* matrix, int k, int m, const uint8_t* data,
   gf8_encode(matrix, k, m, dptr, pptr, n);
 }
 
-// Fused stripe-layout encode over the stripe range [s0, s0+nS) of a
-// LARGER [S, k, cs] batch whose shard rows are shard_len bytes apart:
-// the strided body that lets callers split one batch across worker
-// threads (each thread owns a disjoint stripe range, so the writes
-// never overlap and the bytes are identical to one serial pass).
-// in: the range's first stripe (caller pre-offsets); shards: the FULL
-// output base. cs % 8 == 0.
-void gf8_encode_stripes_block(const int* matrix, int k, int m, int64_t s0,
-                              int64_t nS, int64_t cs, int64_t shard_len,
-                              const uint8_t* in, uint8_t* shards) {
-  const uint8_t* dptr[32];
-  uint8_t* pptr[32];
-#ifdef CEPH_TPU_GFNI
-  if (m <= 8) {
-    // affine table built ONCE for the whole batch (r5 review: building
-    // it per stripe cost as much as the vector work at small chunks)
-    uint64_t aff[32 * 8];
-    for (int i = 0; i < m; ++i)
-      for (int j = 0; j < k; ++j)
-        aff[i * k + j] = gf8_affine_matrix((uint8_t)matrix[i * k + j]);
-    for (int64_t s = s0; s < s0 + nS; ++s) {
-      const uint8_t* base = in + (s - s0) * k * cs;
-      for (int j = 0; j < k; ++j) {
-        dptr[j] = base + j * cs;
-        std::memcpy(shards + j * shard_len + s * cs, dptr[j], cs);
-      }
-      for (int i = 0; i < m; ++i)
-        pptr[i] = shards + (k + i) * shard_len + s * cs;
-      gf8_encode_gfni(aff, k, m, dptr, pptr, cs);
-    }
-    return;
-  }
-#endif
-  for (int64_t s = s0; s < s0 + nS; ++s) {
-    const uint8_t* base = in + (s - s0) * k * cs;
-    for (int j = 0; j < k; ++j) {
-      dptr[j] = base + j * cs;
-      std::memcpy(shards + j * shard_len + s * cs, dptr[j], cs);
-    }
-    for (int i = 0; i < m; ++i)
-      pptr[i] = shards + (k + i) * shard_len + s * cs;
-    gf8_encode(matrix, k, m, dptr, pptr, cs);
-  }
-}
-
-// Fused stripe-layout encode: one pass over the client buffer produces
-// the per-shard buffers (the OSD's deliverable) AND the parity — no
-// separate transpose pass re-reading the data (the ceph_tpu codec
-// stack's hot entry; ECUtil::encode's per-stripe loop collapsed).
-// in: [S, k, cs] stripes; shards: flat [(k+m), S*cs] output whose rows
-// are the shard buffers. cs % 8 == 0.
-void gf8_encode_stripes(const int* matrix, int k, int m, int64_t S,
-                        int64_t cs, const uint8_t* in, uint8_t* shards) {
-  gf8_encode_stripes_block(matrix, k, m, 0, S, cs, S * cs, in, shards);
-}
-
 void gf8_mul_region(uint8_t c, const uint8_t* src, uint8_t* dst, int64_t n) {
   const uint64_t* s = reinterpret_cast<const uint64_t*>(src);
   uint64_t* d = reinterpret_cast<uint64_t*>(dst);

@@ -127,7 +127,7 @@ class TestRemoteLaneIdentity:
             recs = feeder.dispatch.flight.dump()["launches"]
             assert recs and all(
                 r.get("remote_served") in
-                ("device", "mesh", "native_direct", "fallback")
+                ("device", "mesh", "fallback", "fallback_direct")
                 for r in recs if r.get("lane") == "remote"
             ), recs
 
@@ -402,37 +402,27 @@ class TestCrossClientCoalescing:
                 "osd_ec_dispatch_window": 0.05,
             }))
             await acc.start()
-            # force the jax batch lane on the accelerator (the native
-            # per-op lane never coalesces, by design)
-            from ceph_tpu.utils import native as _native
-
-            _native.host_engine_active()
-            saved = _native._HOST_ACTIVE
-            _native._HOST_ACTIVE = False
-            try:
-                f1 = _Feeder("osd.1", acc.addr)
-                f2 = _Feeder("osd.2", acc.addr)
-                o1, o2 = await asyncio.gather(
-                    f1.dispatch.encode(sinfo, codec, b1),
-                    f2.dispatch.encode(sinfo, codec, b2),
-                )
-                _assert_shards_equal(o1, ec_util.encode(sinfo, codec, b1))
-                _assert_shards_equal(o2, ec_util.encode(sinfo, codec, b2))
-                t = acc.dispatch._totals
-                assert t["cross_client_batches"] >= 1
-                recs = acc.dispatch.flight.dump()["launches"]
-                shared = [r for r in recs
-                          if len(r.get("clients") or []) > 1]
-                assert shared, recs
-                assert set(shared[-1]["clients"]) == {"osd.1", "osd.2"}
-                # the service half mirrors the total
-                acc._sync_cross_client()
-                assert acc.perf.get("accel").get(
-                    "cross_client_batches") >= 1
-                await f1.stop()
-                await f2.stop()
-            finally:
-                _native._HOST_ACTIVE = saved
+            f1 = _Feeder("osd.1", acc.addr)
+            f2 = _Feeder("osd.2", acc.addr)
+            o1, o2 = await asyncio.gather(
+                f1.dispatch.encode(sinfo, codec, b1),
+                f2.dispatch.encode(sinfo, codec, b2),
+            )
+            _assert_shards_equal(o1, ec_util.encode(sinfo, codec, b1))
+            _assert_shards_equal(o2, ec_util.encode(sinfo, codec, b2))
+            t = acc.dispatch._totals
+            assert t["cross_client_batches"] >= 1
+            recs = acc.dispatch.flight.dump()["launches"]
+            shared = [r for r in recs
+                      if len(r.get("clients") or []) > 1]
+            assert shared, recs
+            assert set(shared[-1]["clients"]) == {"osd.1", "osd.2"}
+            # the service half mirrors the total
+            acc._sync_cross_client()
+            assert acc.perf.get("accel").get(
+                "cross_client_batches") >= 1
+            await f1.stop()
+            await f2.stop()
             await acc.stop()
 
         run(main())
@@ -508,7 +498,6 @@ class TestLiveClusterAccel:
                 local_served = sum(
                     osd.ec_dispatch._totals["failovers"]
                     + osd.ec_dispatch._totals["lanes"]["device"]["batches"]
-                    + osd.ec_dispatch._totals["native_direct"]
                     + osd.ec_dispatch._totals["fallback_direct"]
                     for osd in cluster.osds.values()
                 )
@@ -580,18 +569,13 @@ class TestLiveClusterAccel:
 
         run(main())
 
-    def test_tripped_accelerator_sheds_to_local_lanes(self, monkeypatch):
+    def test_tripped_accelerator_sheds_to_local_lanes(self):
         """ec_inject_engine_failure=1 ON THE ACCELERATOR trips its
         breaker; its beacon says TRIPPED and the OSDs route the next
         batches to their local lanes (routed_away counts, zero failed
         ops).  Lifting the injection re-promotes via the accelerator's
         canary, a healthy beacon arrives, and traffic returns."""
         from ceph_tpu.rados import MiniCluster
-        from ceph_tpu.utils import native
-
-        # force the jax batch lane (the native per-op lane never
-        # injects — there is no device to lose there)
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
 
         async def main():
             async with MiniCluster(

@@ -3,6 +3,7 @@ with the device check injected (the chip run itself is
 ``python chip_smoke.py`` on a TPU)."""
 
 import importlib.util
+import json
 import pathlib
 import subprocess
 import sys
@@ -28,13 +29,10 @@ def smoke():
 
 
 @pytest.fixture
-def device_lane(monkeypatch):
-    """Serve EC from the jax lane as on a chip (the CPU host would
-    otherwise take the native C lane), and keep the compile cache off."""
-    from ceph_tpu.utils import arch, native
+def no_compile_cache(monkeypatch):
+    """Keep the persistent compile cache off for the CPU run."""
+    from ceph_tpu.utils import arch
 
-    native.host_engine_active()
-    monkeypatch.setattr(native, "_HOST_ACTIVE", False)
     monkeypatch.setattr(arch, "configure_compile_cache", lambda: "off")
 
 
@@ -44,7 +42,8 @@ def _cpu(n):
     return lambda: jax.devices()[:n]
 
 
-def test_all_phases_at_tiny_size(smoke, device_lane, monkeypatch, capsys):
+def test_all_phases_at_tiny_size(smoke, no_compile_cache, monkeypatch,
+                                 capsys):
     # RS(2,1) for the served pool and the CRUSH EC rule: the CPU compile
     # of the 11-wide indep rule alone takes minutes
     monkeypatch.setattr(smoke, "RS83", {**smoke.RS83, "k": "2", "m": "1"})
@@ -56,10 +55,14 @@ def test_all_phases_at_tiny_size(smoke, device_lane, monkeypatch, capsys):
         assert f"phase {phase}: ok" in out
     assert out.count("byte-exact") >= 5 * 5  # encode + 4 decodes each
     assert "on the vectorized backend" in out
-    assert '"native_direct": 0' in out
+    marker = "served: ec_dispatch totals over all OSDs "
+    line = next(ln for ln in out.splitlines() if marker in ln)
+    totals = json.loads(line.split(marker, 1)[1])
+    assert totals["device_ops"] > 0
+    assert totals["fallback_direct"] == 0
 
 
-def test_four_chip_mesh_phase(smoke, device_lane, capsys):
+def test_four_chip_mesh_phase(smoke, no_compile_cache, capsys):
     dev = smoke.run(smoke.Sizes(**TINY), four_chips=True,
                     device_check=_cpu(4))
     assert dev["count"] == 4
@@ -69,12 +72,12 @@ def test_four_chip_mesh_phase(smoke, device_lane, capsys):
 
 
 def test_served_path_fails_on_host_lane(smoke, monkeypatch):
-    """The served phase refuses a run that the native host lane served."""
-    from ceph_tpu.utils import native
+    """The served phase refuses a run that the host fallback served: a
+    breaker held open sends every EC op to the fallback-direct lane."""
+    from ceph_tpu.osd.ec_failover import EngineSupervisor
 
-    native.host_engine_active()
-    monkeypatch.setattr(native, "_HOST_ACTIVE", True)
-    with pytest.raises(smoke.SmokeFailure, match="native_direct|device lane"):
+    monkeypatch.setattr(EngineSupervisor, "device_ok", lambda self: False)
+    with pytest.raises(smoke.SmokeFailure, match="fallback_direct = [1-9]"):
         smoke.phase_served(smoke.Sizes(**{**TINY, "served_objects": 2}),
                            "cpu")
 

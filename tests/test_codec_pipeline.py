@@ -16,7 +16,6 @@ from ceph_tpu.models import matrix_codec
 from ceph_tpu.ops import gf_pallas
 from ceph_tpu.ops.gf import gf
 from ceph_tpu.ops.profiler import profiler
-from ceph_tpu.utils import native
 
 SLICE = 4 * gf_pallas.BLOCK  # bytes per row of one slice
 
@@ -34,9 +33,6 @@ def rs83(monkeypatch):
     monkeypatch.setattr(
         matrix_codec, "_gf_matmul_u32",
         functools.partial(matrix_codec._gf_matmul_u32, slice_bytes=SLICE))
-    # keep decode on the device path: a CPU host's native engine would
-    # otherwise take the u32 decode branch's place
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
     return instance().factory(
         "isa", {"technique": "reed_sol_van", "k": "8", "m": "3"})
 

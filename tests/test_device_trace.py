@@ -35,7 +35,6 @@ from ceph_tpu.ops.device_trace import (
 from ceph_tpu.ops.profiler import profiler
 from ceph_tpu.osd import ec_util
 from ceph_tpu.osd.ec_dispatch import ECDispatcher
-from ceph_tpu.utils import native
 
 GOLDEN = pathlib.Path(__file__).parent / "golden" / "device_trace_events.json"
 
@@ -201,11 +200,10 @@ class TestWindowService:
         assert "unavailable" not in d or "still open" not in str(d)
         assert svc.status()["active"] is False
 
-    def test_dispatcher_batch_window_round_trip(self, monkeypatch):
+    def test_dispatcher_batch_window_round_trip(self):
         """The acceptance path: start -> one dispatcher EC batch ->
         stop -> dump returns a non-empty per-engine breakdown carrying
         all three buckets, merged into the KernelProfiler entries."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         profiler().reset()
         sinfo, codec = _sinfo(), _codec()
         rng = np.random.default_rng(3)
@@ -243,13 +241,12 @@ class TestWindowService:
         assert d["buckets"] == bd["buckets"]
         assert svc.status()["windows"] >= 1
 
-    def test_mesh_reconstruct_window_splits_ici(self, monkeypatch):
+    def test_mesh_reconstruct_window_splits_ici(self):
         """A mesh reconstruct window attributes nonzero time to the
         ICI-collective bucket DISTINCTLY from the rebuild compute —
         the "gather-bound or rebuild-bound?" answer, measured."""
         from ceph_tpu.parallel.engine import MeshEcEngine
 
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         sinfo, codec = _sinfo(), _codec()
         eng = MeshEcEngine()
         rng = np.random.default_rng(4)
@@ -310,10 +307,9 @@ class TestFlightRecorder:
         assert "in_flight" not in hit
         assert hit["served"] == "fallback" and "EngineFault" in hit["error"]
 
-    def test_dispatcher_records_launches(self, monkeypatch):
+    def test_dispatcher_records_launches(self):
         """Batched launches land in the ring with lane / QoS class /
         queue-wait vs device wall / the slowest member's trace id."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         sinfo, codec = _sinfo(), _codec()
         rng = np.random.default_rng(5)
         bufs = [
@@ -350,19 +346,24 @@ class TestFlightRecorder:
         assert rec["stripe_width"] == sinfo.stripe_width
         assert hit is not None and hit["seq"] == rec["seq"]
 
-    def test_native_direct_lane_records_too(self):
-        """On a CPU host the native lane serves most traffic — a slow
-        op carried by a per-op native call must still name its
-        launch."""
-        if not native.host_engine_active():
-            pytest.skip("no native engine in this container")
+    def test_fallback_direct_lane_records_too(self):
+        """While the breaker is TRIPPED the host fallback serves each
+        op on its own — a slow op carried by such a per-op call must
+        still name its launch."""
+        from ceph_tpu.osd.ec_failover import TRIPPED, EngineSupervisor
+
         sinfo, codec = _sinfo(2, 512), _codec()
         buf = np.arange(2 * sinfo.stripe_width, dtype=np.uint32).astype(
             np.uint8
         )
 
         async def main():
-            disp = ECDispatcher(window=0.002)
+            sup = EngineSupervisor(probe_interval=30.0)  # no re-promote
+            disp = ECDispatcher(window=0.0, supervisor=sup)
+            disp.inject_engine_failure = 1
+            for _ in range(2):  # two fatals: SUSPECT then TRIPPED
+                await disp.encode(sinfo, codec, buf)
+            assert sup.state == TRIPPED
             tok = current_trace.set("client.0:t77")
             try:
                 await disp.encode(sinfo, codec, buf)
@@ -374,7 +375,8 @@ class TestFlightRecorder:
 
         hit = run(main())
         assert hit is not None
-        assert hit["lane"] == "native_direct"
+        assert hit["lane"] == "fallback_direct"
+        assert hit["served"] == "fallback_direct"
         assert hit["ops"] == 1 and hit["device_wall_s"] > 0
 
     def test_op_tracker_dump_names_the_launch(self):
@@ -405,15 +407,13 @@ class TestFlightRecorder:
 
 
 class TestLiveCluster:
-    def test_kernel_trace_and_launch_history_admin(self, monkeypatch,
-                                                   tmp_path):
+    def test_kernel_trace_and_launch_history_admin(self, tmp_path):
         """The operator surface end to end on a live MiniCluster:
         `kernel trace start` -> EC writes -> `kernel trace dump`
         returns the per-engine breakdown over every daemon's socket;
         `dump_launch_history` names the launch (lane, batch key, QoS
         class) that carried an injected slow op; an open window across
         the PR-7 fault matrix adds zero failed client ops."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         from ceph_tpu.common.admin_socket import admin_command
         from ceph_tpu.rados import MiniCluster
 

@@ -25,7 +25,6 @@ from ceph_tpu.ops import matrices as mx
 from ceph_tpu.ops.profiler import profiler
 from ceph_tpu.osd import ec_util
 from ceph_tpu.osd.ec_dispatch import ECDispatcher, bucket_stripes
-from ceph_tpu.utils import native
 
 
 def run(coro):
@@ -68,19 +67,18 @@ def test_bucket_stripes_boundaries():
 # -- byte identity vs the per-op oracle --------------------------------------
 
 
-@pytest.mark.parametrize("force_jax", [False, True])
-def test_encode_bytes_identical_mixed_sizes(monkeypatch, force_jax):
-    """Coalesced output == per-op ec_util.encode, on both engine routes
-    (native C direct lane, and the jax batch+bucket path)."""
-    if force_jax:
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
+@pytest.mark.parametrize("bucket", [False, True])
+def test_encode_bytes_identical_mixed_sizes(bucket):
+    """Coalesced output == per-op ec_util.encode, with and without the
+    power-of-two shape bucketing (pad rows sliced back off)."""
     k, m = 2, 1
     sinfo, codec = _sinfo(k), _codec(k, m)
     sizes = [1, 2, 3, 4, 5, 7, 8, 9]
     bufs = _bufs(sinfo, sizes)
 
     async def main():
-        disp = ECDispatcher(window=0.005, max_stripes=1 << 20)
+        disp = ECDispatcher(window=0.005, max_stripes=1 << 20,
+                            bucket=bucket)
         outs = await asyncio.gather(
             *[disp.encode(sinfo, codec, b) for b in bufs]
         )
@@ -93,9 +91,8 @@ def test_encode_bytes_identical_mixed_sizes(monkeypatch, force_jax):
 
 
 @pytest.mark.parametrize("stripes", [1, 8, 9, 16, 17])
-def test_encode_bucket_boundary_sizes(monkeypatch, stripes):
+def test_encode_bucket_boundary_sizes(stripes):
     """S=1, S=2^n, S=2^n+1 single-op batches survive the pad+slice."""
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
     sinfo, codec = _sinfo(2), _codec()
     (buf,) = _bufs(sinfo, [stripes], seed=stripes)
 
@@ -108,12 +105,11 @@ def test_encode_bucket_boundary_sizes(monkeypatch, stripes):
     _assert_same_shards(run(main()), ec_util.encode(sinfo, codec, buf))
 
 
-@pytest.mark.parametrize("force_jax", [False, True])
-def test_decode_bytes_identical(monkeypatch, force_jax):
+@pytest.mark.parametrize("bucket", [False, True])
+def test_decode_bytes_identical(bucket):
     """Coalesced decode_concat == per-op ec_util.decode_concat for a
-    degraded read (data shard missing) across mixed sizes."""
-    if force_jax:
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
+    degraded read (data shard missing) across mixed sizes, with and
+    without shape bucketing."""
     k, m = 2, 1
     sinfo, codec = _sinfo(k), _codec(k, m)
     sizes = [1, 2, 4, 5]
@@ -126,7 +122,8 @@ def test_decode_bytes_identical(monkeypatch, force_jax):
         chunk_maps.append({1: enc[1], 2: enc[2]})
 
     async def main():
-        disp = ECDispatcher(window=0.005, max_stripes=1 << 20)
+        disp = ECDispatcher(window=0.005, max_stripes=1 << 20,
+                            bucket=bucket)
         outs = await asyncio.gather(
             *[disp.decode_concat(sinfo, codec, c) for c in chunk_maps]
         )
@@ -138,16 +135,16 @@ def test_decode_bytes_identical(monkeypatch, force_jax):
     for b, c, got in zip(bufs, chunk_maps, outs):
         assert got == ec_util.decode_concat(sinfo, codec, c)
         assert got == b.tobytes()
-    if force_jax:  # all four requests coalesced into one launch
-        assert st["totals"]["batches"] == 1
-        assert st["totals"]["ops"] == 4
+    # all four requests coalesced into one launch
+    assert st["totals"]["batches"] == 1
+    assert st["totals"]["ops"] == 4
+    assert st["totals"]["pad_stripes"] == (4 if bucket else 0)  # 12 -> 16
 
 
 # -- flush policy ------------------------------------------------------------
 
 
-def test_flush_on_threshold_beats_window(monkeypatch):
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
+def test_flush_on_threshold_beats_window():
     sinfo, codec = _sinfo(2), _codec()
     bufs = _bufs(sinfo, [2, 2], seed=1)
 
@@ -172,8 +169,7 @@ def test_flush_on_threshold_beats_window(monkeypatch):
         _assert_same_shards(got, ec_util.encode(sinfo, codec, b))
 
 
-def test_flush_on_window(monkeypatch):
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
+def test_flush_on_window():
     sinfo, codec = _sinfo(2), _codec()
     (buf,) = _bufs(sinfo, [2], seed=2)
 
@@ -191,11 +187,10 @@ def test_flush_on_window(monkeypatch):
     _assert_same_shards(out, ec_util.encode(sinfo, codec, buf))
 
 
-def test_no_bucket_overshoot(monkeypatch):
+def test_no_bucket_overshoot():
     """An op that would push the batch past the threshold flushes the
     queued ops at their snug bucket first — pad waste stays bounded by
     the bucket below max_stripes, not doubled past it."""
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
     sinfo, codec = _sinfo(2), _codec()
     # 3+3 stripes fill toward max_stripes=4: admitting the second op
     # would make 6 -> bucket 8 (100% overshoot); instead op 1 launches
@@ -219,10 +214,9 @@ def test_no_bucket_overshoot(monkeypatch):
         _assert_same_shards(got, ec_util.encode(sinfo, codec, b))
 
 
-def test_cancelled_waiter_does_not_wedge_batch(monkeypatch):
+def test_cancelled_waiter_does_not_wedge_batch():
     """Op abort: a cancelled queued waiter is dropped; the surviving
     ops' batch still launches and answers."""
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
     sinfo, codec = _sinfo(2), _codec()
     buf_a, buf_b = _bufs(sinfo, [1, 4], seed=5)
 
@@ -248,7 +242,6 @@ def test_cancelled_waiter_does_not_wedge_batch(monkeypatch):
 def test_batch_failure_reaches_every_waiter(monkeypatch):
     """A codec blowing up inside the worker thread rejects all waiters
     instead of wedging them."""
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
     sinfo, codec = _sinfo(2), _codec()
     bufs = _bufs(sinfo, [1, 2], seed=6)
 
@@ -275,7 +268,6 @@ def test_batch_task_ended_unanswered_cancels_its_waiters(monkeypatch):
     catch (here a CancelledError leaking out of the lane) must not
     leave its waiters pending for ever: they are cancelled, so the op
     task ends and the client resends."""
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
     sinfo, codec = _sinfo(2), _codec()
     (buf,) = _bufs(sinfo, [1], seed=7)
 
@@ -299,7 +291,6 @@ def test_event_loop_survives_long_encode(monkeypatch):
     """The liberation bound: while a (deliberately slow) encode runs in
     the dispatcher's worker thread, the event loop keeps scheduling —
     the heartbeat-tick survival property, measured as max loop stall."""
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
     sinfo, codec = _sinfo(2), _codec()
     (buf,) = _bufs(sinfo, [2], seed=7)
 
@@ -341,11 +332,10 @@ def test_event_loop_survives_long_encode(monkeypatch):
 # -- the anti-compile-storm gate ---------------------------------------------
 
 
-def test_size_sweep_jit_misses_bounded_by_buckets(monkeypatch):
+def test_size_sweep_jit_misses_bounded_by_buckets():
     """50 distinct op sizes through the dispatcher cost at most
     #buckets jit-cache signatures (the KernelProfiler's first-sighting
     misses), not 50 — the compile-storm fix the bucketing exists for."""
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
     # a geometry no other test uses, so profiler signatures are fresh
     k, m = 5, 2
     sinfo = ec_util.StripeInfo(stripe_width=256 * k, chunk_size=256)
@@ -384,8 +374,7 @@ def test_size_sweep_jit_misses_bounded_by_buckets(monkeypatch):
 # -- perf-counter wiring -----------------------------------------------------
 
 
-def test_perf_counters_and_histogram_land(monkeypatch):
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
+def test_perf_counters_and_histogram_land():
     from ceph_tpu.common.perf_counters import (
         PerfCounters, PerfHistogramAxis,
     )
@@ -399,7 +388,6 @@ def test_perf_counters_and_histogram_land(monkeypatch):
     pec.add_counter("dispatch_flush_stop")
     pec.add_counter("dispatch_pad_stripes")
     pec.add_counter("dispatch_pad_bytes")
-    pec.add_counter("dispatch_native_direct")
     pec.add_avg("dispatch_occupancy")
     pec.add_histogram(
         "dispatch_batch_size_histogram",
@@ -441,28 +429,54 @@ def test_perf_counters_and_histogram_land(monkeypatch):
             ["count"] == 1)
 
 
-def test_native_direct_lane(monkeypatch):
-    """With the native C engine active, requests skip coalescing but
-    still run in the worker pool (and are counted)."""
-    if not native.host_engine_active():
-        pytest.skip("native engine unavailable on this host")
-    sinfo, codec = _sinfo(2), _codec()
-    bufs = _bufs(sinfo, [2, 3], seed=10)
+@pytest.mark.parametrize("op", ["encode", "decode"])
+@pytest.mark.parametrize("k,m", [(2, 1), (4, 2), (8, 3)])
+def test_default_dispatcher_serves_from_device_lane(k, m, op):
+    """On the CPU backend, with nothing patched, a default dispatcher
+    serves concurrent encodes and a reconstruct from its device lane —
+    the lane a chip serves — with the bytes of the codec's host oracle."""
+    sinfo, codec = _sinfo(k), _codec(k, m)
+    bufs = _bufs(sinfo, [1, 2, 3], seed=11 + k)
+    lost = list(range(m))  # m data shards: a full-strength reconstruct
+    chunk_maps = [
+        {s: v for s, v in ec_util.encode(sinfo, codec, b).items()
+         if s not in lost}
+        for b in bufs
+    ]
 
     async def main():
-        disp = ECDispatcher(window=30.0, max_stripes=4)
-        outs = await asyncio.gather(
-            *[disp.encode(sinfo, codec, b) for b in bufs]
-        )
+        disp = ECDispatcher()
+        if op == "encode":
+            outs = await asyncio.gather(
+                *[disp.encode(sinfo, codec, b) for b in bufs])
+        else:
+            outs = await asyncio.gather(
+                *[disp.decode_concat(sinfo, codec, c) for c in chunk_maps])
         st = disp.dump()
         await disp.stop()
         return outs, st
 
     outs, st = run(main())
-    assert st["totals"]["native_direct"] == 2
-    assert st["totals"]["batches"] == 0  # nothing queued
-    for b, got in zip(bufs, outs):
-        _assert_same_shards(got, ec_util.encode(sinfo, codec, b))
+    totals = st["totals"]
+    assert totals["lanes"]["device"]["batches"] >= 1
+    assert totals["lanes"]["device"]["ops"] == len(bufs)
+    assert totals["fallback_direct"] == 0
+    assert not any("native" in key for key in totals)  # no host lane
+    for b, c, got in zip(bufs, chunk_maps, outs):
+        data = np.ascontiguousarray(
+            b.reshape(-1, k, CS).transpose(1, 0, 2)).reshape(k, -1)
+        if op == "encode":
+            parity = codec.encode_chunks_host(data)
+            for s in range(k):
+                assert np.array_equal(np.asarray(got[s]), data[s])
+            for j in range(m):
+                assert np.array_equal(np.asarray(got[k + j]), parity[j])
+            continue
+        present = sorted(c)
+        rebuilt = codec.decode_chunks_host(
+            present, np.stack([c[s] for s in present]), lost)
+        assert np.array_equal(rebuilt, data[lost])
+        assert bytes(got) == b.tobytes()
 
 
 # -- OSD integration ---------------------------------------------------------
@@ -470,7 +484,7 @@ def test_native_direct_lane(monkeypatch):
 
 def test_osd_routes_ec_writes_through_dispatcher():
     """An EC write on a live mini-cluster lands dispatcher activity on
-    the primary's ec counters (osd_ec_dispatch defaults on)."""
+    the primary's ec counters."""
     from ceph_tpu.rados import MiniCluster
 
     async def main():
@@ -485,14 +499,52 @@ def test_osd_routes_ec_writes_through_dispatcher():
             assert await io.read("obj") == payload
             served = 0
             for osd in cluster.osds.values():
-                assert osd.ec_dispatch is not None
                 pec = osd.perf.get("ec")
                 served += pec.get("dispatch_ops")
-                served += pec.get("dispatch_native_direct")
                 # admin surface serves the dispatcher dump
                 assert "totals" in osd.ec_dispatch.dump()
             assert served > 0, "no EC op went through the dispatcher"
         finally:
             await cluster.stop()
+
+    run(main())
+
+
+def test_cluster_ec_ops_ride_the_device_lane():
+    """Tier-1's default path is the chip's path: on the CPU backend,
+    with nothing patched, an EC pool's write_full and a degraded read
+    are served by the dispatcher's device lane, never the host
+    fallback, and read back byte for byte."""
+    from ceph_tpu.rados import MiniCluster
+
+    def device_ops(cluster):
+        ops = 0
+        for osd in cluster.osds.values():
+            totals = osd.ec_dispatch.dump()["totals"]
+            assert totals["fallback_direct"] == 0
+            assert totals["failovers"] == 0
+            ops += totals["lanes"]["device"]["ops"]
+        return ops
+
+    async def main():
+        async with MiniCluster(n_osds=4) as cluster:
+            cl = await cluster.client()
+            await cl.create_pool("ec", "erasure")
+            io = cl.io_ctx("ec")
+            payload = np.random.default_rng(12).integers(
+                0, 256, size=48 << 10, dtype=np.uint8).tobytes()
+            await io.write_full("obj", payload)
+            written = device_ops(cluster)
+            assert written > 0, "the write was not served by the device lane"
+            pool = cl.osdmap.lookup_pool("ec")
+            _, acting, primary = cl.osdmap.object_to_acting("obj", pool.id)
+            # a data shard (the default profile is k=2) other than the
+            # primary's: the read must reconstruct it
+            victim = next(o for o in acting[:2] if o != primary)
+            await cluster.kill_osd(victim)
+            await cluster.wait_for_osd_down(victim)
+            assert await io.read("obj") == payload
+            assert device_ops(cluster) > written, (
+                "the degraded read was not served by the device lane")
 
     run(main())

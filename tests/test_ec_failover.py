@@ -44,7 +44,6 @@ from ceph_tpu.osd.ec_failover import (
     TRIPPED,
     EngineSupervisor,
 )
-from ceph_tpu.utils import native
 
 
 def run(coro):
@@ -203,13 +202,10 @@ class TestHostFallbackEngine:
 
 
 class TestDispatcherFailover:
-    def test_fatal_error_mid_batch_replays_no_waiter_fails(
-        self, monkeypatch
-    ):
+    def test_fatal_error_mid_batch_replays_no_waiter_fails(self):
         """The acceptance core: injection fires mid-batch, every waiter
         still gets oracle-identical bytes; failovers/replayed_ops
         count; the breaker walks HEALTHY -> SUSPECT -> TRIPPED."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         sinfo, codec = _sinfo(2), _codec()
         bufs = [_buf(sinfo, s, seed=s) for s in (2, 3)]
         wants = [ec_util.encode(sinfo, codec, b) for b in bufs]
@@ -244,12 +240,9 @@ class TestDispatcherFailover:
         assert st2["totals"]["fallback_direct"] == 1
         assert st2["engine_health"]["state"] == "tripped"
 
-    def test_data_error_surfaces_and_breaker_stays_closed(
-        self, monkeypatch
-    ):
+    def test_data_error_surfaces_and_breaker_stays_closed(self):
         """A shape bug is the CALLER's: it must raise (not replay) and
         must not move the breaker."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         sinfo, codec = _sinfo(2), _codec()
         buf = _buf(sinfo, 2, seed=5)
 
@@ -274,10 +267,9 @@ class TestDispatcherFailover:
 
         run(main())
 
-    def test_live_disable_restores_fail_fast(self, monkeypatch):
+    def test_live_disable_restores_fail_fast(self):
         """osd_ec_engine_failover=false (live): fatal errors surface to
         the waiters — the pre-failover contract."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         sinfo, codec = _sinfo(2), _codec()
         buf = _buf(sinfo, 2, seed=6)
 
@@ -292,15 +284,12 @@ class TestDispatcherFailover:
 
         run(main())
 
-    def test_live_disable_while_tripped_clears_degraded(
-        self, monkeypatch
-    ):
+    def test_live_disable_while_tripped_clears_degraded(self):
         """Disabling the failover while TRIPPED must restore the
         pre-failover world completely: state back to HEALTHY (gauge
         clears -> ACCEL_DEGRADED drops) and the QoS capacity squeeze
         released — a breaker the operator turned off must not keep
         throttling the cluster."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         sinfo, codec = _sinfo(2), _codec()
 
         async def main():
@@ -323,14 +312,11 @@ class TestDispatcherFailover:
 
         run(main())
 
-    def test_inline_shutdown_lane_routes_around_a_tripped_device(
-        self, monkeypatch
-    ):
+    def test_inline_shutdown_lane_routes_around_a_tripped_device(self):
         """The _stopping inline path runs ON the event loop: with the
         breaker TRIPPED it must use the host fallback — an inline
         device call there would have no deadline, no watchdog pin, and
         would stall the heartbeat tasks themselves."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         sinfo, codec = _sinfo(2), _codec()
         buf = _buf(sinfo, 2, seed=23)
 
@@ -366,7 +352,6 @@ class TestDispatcherFailover:
     ):
         """If the replay itself fails, THAT error reaches the waiters
         (it describes the actual state of the bytes)."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         sinfo, codec = _sinfo(2), _codec()
         buf = _buf(sinfo, 2, seed=7)
 
@@ -386,8 +371,7 @@ class TestDispatcherFailover:
 
         run(main())
 
-    def test_decode_replays_too(self, monkeypatch):
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
+    def test_decode_replays_too(self):
         sinfo, codec = _sinfo(2), _codec()
         buf = _buf(sinfo, 4, seed=8)
         enc = ec_util.encode(sinfo, codec, buf)
@@ -412,15 +396,12 @@ class TestDispatcherFailover:
 
 
 class TestLaunchDeadline:
-    def test_hang_fails_over_at_deadline_and_pins_watchdog(
-        self, monkeypatch
-    ):
+    def test_hang_fails_over_at_deadline_and_pins_watchdog(self):
         """ec_inject_launch_hang: the waiters fail over at
         osd_ec_launch_deadline (far before the hang resolves), the
         breaker trips, launch_deadline_timeouts counts, and the wedged
         thread stays pinned on the HeartbeatMap handle — grace blows
         while it is stuck, clears when it returns."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         sinfo, codec = _sinfo(2), _codec()
         buf = _buf(sinfo, 2, seed=9)
         want = ec_util.encode(sinfo, codec, buf)
@@ -457,15 +438,12 @@ class TestLaunchDeadline:
         _same_shards(out2, want)
 
 
-    def test_wedged_canaries_never_starve_the_fallback_lane(
-        self, monkeypatch
-    ):
+    def test_wedged_canaries_never_starve_the_fallback_lane(self):
         """Review finding: while the device stays wedged, every canary
         probe times out too — each one must respawn the executor like a
         launch does, or two wedged probes eat both worker slots and the
         fallback serving lane deadlocks (exactly the silent freeze the
         feature exists to prevent)."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         sinfo, codec = _sinfo(2), _codec()
         buf = _buf(sinfo, 2, seed=11)
         want = ec_util.encode(sinfo, codec, buf)
@@ -505,8 +483,7 @@ class TestLaunchDeadline:
 
 
 class TestRepromotion:
-    def test_probe_repromotes_after_injection_lifts(self, monkeypatch):
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
+    def test_probe_repromotes_after_injection_lifts(self):
         from ceph_tpu.common.perf_counters import PerfCounters
 
         pec = PerfCounters("ec")
@@ -561,7 +538,6 @@ class TestRepromotion:
         decode canary: a device whose reconstruct program is broken
         but whose encode works would otherwise flap TRIPPED->HEALTHY->
         TRIPPED forever."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         sinfo, codec = _sinfo(2), _codec()
         shards = ec_util.encode_fallback(sinfo, codec,
                                          _buf(sinfo, 2, seed=11))
@@ -616,7 +592,7 @@ class TestRepromotion:
         assert degraded == [True]  # no duplicate degraded edge
         assert sup.last_transition == t_trip
 
-    def test_engine_state_gauge_survives_perf_reset(self, monkeypatch):
+    def test_engine_state_gauge_survives_perf_reset(self):
         """An admin `perf reset` zeroes gauges; refresh_gauge (run off
         the OSD report tick) must re-assert engine_state or a TRIPPED
         OSD would read healthy at the mgr and silently clear
@@ -680,18 +656,13 @@ async def _mgr_health(client):
 
 
 class TestFaultMatrixLive:
-    def test_error_and_hang_injection_on_a_live_cluster(
-        self, monkeypatch
-    ):
+    def test_error_and_hang_injection_on_a_live_cluster(self):
         """ISSUE 7 acceptance: with ec_inject_engine_failure (error and
         hang variants) firing mid-batch on a live MiniCluster, no
         client op fails — in-flight ops replay bit-identically,
         ec.engine_failovers increments, ACCEL_DEGRADED raises at the
         mgr and clears, and the engine re-promotes after the injection
         is lifted."""
-        # force the jax batch route (the native C lane has no device to
-        # lose; trips only happen where the accelerator serves)
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         from ceph_tpu.rados import MiniCluster
 
         async def main():
@@ -798,11 +769,9 @@ class TestFaultMatrixLive:
 
         run(main())
 
-    def test_dump_engine_health_admin_command(self, monkeypatch,
-                                              tmp_path):
+    def test_dump_engine_health_admin_command(self, tmp_path):
         """The operator surface: dump_engine_health serves breaker
         state + failover totals over the admin socket."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         from ceph_tpu.common.admin_socket import admin_command
         from ceph_tpu.rados import MiniCluster
 

@@ -1,6 +1,6 @@
 """The mesh dispatcher lane (ISSUE 8): multi-chip EC as a first-class
 route through the cross-op microbatch dispatcher — byte identity vs the
-native oracle across bucket boundaries / uneven mesh remainders / w=16
+per-op oracle across bucket boundaries / uneven mesh remainders / w=16
 codecs / mid-batch cancellation, the prime-k reconstruct fallback, the
 mesh-lane anti-compile-storm gate (<= #buckets x #mesh-slices
 compiles), per-lane observability, and the live fault matrix (injected
@@ -24,7 +24,6 @@ from ceph_tpu.osd.ec_dispatch import (
     bucket_stripes_aligned,
 )
 from ceph_tpu.parallel.engine import MeshEcEngine
-from ceph_tpu.utils import native
 
 
 def run(coro):
@@ -99,8 +98,8 @@ def test_bucket_stripes_aligned_rule():
 
 
 class TestMeshLaneBytes:
-    """Dispatcher mesh-lane outputs bit-identical to the per-op native
-    oracle (ec_util) across bucket boundaries, uneven ΣS % mesh_size
+    """Dispatcher mesh-lane outputs bit-identical to the per-op oracle
+    (ec_util) across bucket boundaries, uneven ΣS % mesh_size
     remainders, and w=16 codecs."""
 
     @pytest.mark.parametrize("sizes", [
@@ -108,8 +107,7 @@ class TestMeshLaneBytes:
         [5, 3],          # ΣS=8: snug on an 8-chip mesh
         [7, 6, 4],       # ΣS=17: crosses the 16-stripe bucket boundary
     ])
-    def test_encode_identical_mixed_sizes(self, monkeypatch, sizes):
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
+    def test_encode_identical_mixed_sizes(self, sizes):
         sinfo, codec = _sinfo(2), _codec(2, 1)
         bufs = _bufs(sinfo, sizes, seed=21)
         eng = _engine()
@@ -133,8 +131,7 @@ class TestMeshLaneBytes:
         for b, got in zip(bufs, outs):
             _assert_same_shards(got, ec_util.encode(sinfo, codec, b))
 
-    def test_decode_identical_through_mesh_lane(self, monkeypatch):
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
+    def test_decode_identical_through_mesh_lane(self):
         k, m = 2, 1
         sinfo, codec = _sinfo(k), _codec(k, m)
         bufs = _bufs(sinfo, [3, 5], seed=22)
@@ -161,10 +158,9 @@ class TestMeshLaneBytes:
         for b, got in zip(bufs, outs):
             assert bytes(got) == b.tobytes()
 
-    def test_decode_without_missing_rows_skips_mesh(self, monkeypatch):
+    def test_decode_without_missing_rows_skips_mesh(self):
         """All wanted rows present -> no reconstruct -> the mesh lane
         does not apply (the old router's gate, kept)."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         sinfo, codec = _sinfo(2), _codec(2, 1)
         (buf,) = _bufs(sinfo, [2], seed=23)
         full = ec_util.encode(sinfo, codec, buf)
@@ -182,8 +178,7 @@ class TestMeshLaneBytes:
         assert bytes(out) == buf.tobytes()
         assert st["totals"]["lanes"]["mesh"]["batches"] == 0
 
-    def test_w16_codec_identical(self, monkeypatch):
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
+    def test_w16_codec_identical(self):
         k, m = 4, 2
         sinfo, codec = _sinfo(k), _codec(k, m, w=16)
         bufs = _bufs(sinfo, [2, 3], seed=24)
@@ -207,12 +202,9 @@ class TestMeshLaneBytes:
             _assert_same_shards(got, ec_util.encode(sinfo, codec, b))
         assert bytes(dec) == bufs[0].tobytes()
 
-    def test_mesh_lane_outranks_native_direct(self):
-        """osd_ec_mesh is an explicit operator opt-in: with the native
-        C engine available the mesh still takes the lane (the old
-        router's precedence, kept)."""
-        if not native.host_engine_active():
-            pytest.skip("native engine unavailable on this host")
+    def test_mesh_lane_outranks_device_lane(self):
+        """osd_ec_mesh is an explicit operator opt-in: a request the
+        mesh routes never takes the single-device lane."""
         sinfo, codec = _sinfo(2), _codec(2, 1)
         (buf,) = _bufs(sinfo, [2], seed=25)
 
@@ -226,7 +218,7 @@ class TestMeshLaneBytes:
 
         out, st = run(main())
         assert st["totals"]["lanes"]["mesh"]["batches"] == 1
-        assert st["totals"]["native_direct"] == 0
+        assert st["totals"]["lanes"]["device"]["batches"] == 0
         _assert_same_shards(out, ec_util.encode(sinfo, codec, buf))
 
     def test_unaligned_chunk_size_stays_off_the_mesh(self):
@@ -241,8 +233,7 @@ class TestMeshLaneBytes:
 # -- mid-batch cancellation on the mesh route ---------------------------------
 
 
-def test_cancelled_waiter_does_not_wedge_mesh_batch(monkeypatch):
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
+def test_cancelled_waiter_does_not_wedge_mesh_batch():
     sinfo, codec = _sinfo(2), _codec(2, 1)
     buf_a, buf_b = _bufs(sinfo, [1, 4], seed=26)
 
@@ -309,11 +300,10 @@ class TestPrimeKFallback:
 # -- the anti-compile-storm gate on the mesh lane -----------------------------
 
 
-def test_mesh_size_sweep_jit_misses_bounded(monkeypatch):
+def test_mesh_size_sweep_jit_misses_bounded():
     """50 distinct op sizes through the mesh lane cost at most
     #buckets x #mesh-slices mesh_encode jit signatures — the
     mesh_size x bucket alignment rule (tier-1, acceptance #3)."""
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
     # a geometry no other test uses, so profiler signatures are fresh
     k, m = 3, 2
     sinfo = ec_util.StripeInfo(stripe_width=256 * k, chunk_size=256)
@@ -357,8 +347,7 @@ def test_mesh_size_sweep_jit_misses_bounded(monkeypatch):
 # -- profiler visibility ------------------------------------------------------
 
 
-def test_mesh_programs_distinct_in_kernel_profile(monkeypatch):
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
+def test_mesh_programs_distinct_in_kernel_profile():
     k, m = 2, 1
     sinfo, codec = _sinfo(k), _codec(k, m)
     (buf,) = _bufs(sinfo, [4], seed=30)
@@ -382,7 +371,7 @@ def test_mesh_programs_distinct_in_kernel_profile(monkeypatch):
     assert "mesh_encode" in profiler().dump_histograms()
 
 
-def test_gather_probe_reports_own_engine(monkeypatch):
+def test_gather_probe_reports_own_engine():
     eng = _engine()
     n = len(eng.devices)
     eng.probe_gather(8, 4 * n * 8)
@@ -392,11 +381,10 @@ def test_gather_probe_reports_own_engine(monkeypatch):
 # -- mesh-lane failover (deterministic, dispatcher level) ---------------------
 
 
-def test_mesh_lane_failover_replays_bit_identical(monkeypatch):
+def test_mesh_lane_failover_replays_bit_identical():
     """A fatal device error mid-mesh-batch replays the whole batch on
     the host fallback (no waiter sees the error, bytes identical) and
     the supervisor attributes the fatal to the mesh lane."""
-    monkeypatch.setattr(native, "host_engine_active", lambda: False)
     from ceph_tpu.osd.ec_failover import EngineSupervisor
 
     sinfo, codec = _sinfo(2), _codec(2, 1)
@@ -427,15 +415,12 @@ def test_mesh_lane_failover_replays_bit_identical(monkeypatch):
 
 
 class TestMeshFaultMatrix:
-    def test_injected_loss_mid_mesh_batch_zero_failed_ops(
-        self, monkeypatch
-    ):
+    def test_injected_loss_mid_mesh_batch_zero_failed_ops(self):
         """ISSUE 8 acceptance: injected device loss mid-mesh-batch
         replays on the host fallback with ZERO failed client ops on a
         live MiniCluster; the supervisor attributes the trip to the
         mesh lane and the canary re-promotes the mesh after the
         injection lifts."""
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
         from ceph_tpu.osd.ec_failover import HEALTHY
         from ceph_tpu.rados import MiniCluster
 

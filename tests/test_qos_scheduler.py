@@ -422,20 +422,13 @@ class TestStarvationGate:
 
 
 class TestECDispatchClassLanes:
-    def test_classes_never_share_a_batch_and_bytes_are_pinned(
-        self, monkeypatch
-    ):
+    def test_classes_never_share_a_batch_and_bytes_are_pinned(self):
         """Client and ec_background encodes submitted in the same tick
         coalesce within their class but never across classes — and
         both lanes stay byte-identical to per-op ec_util.encode."""
         from ceph_tpu.models import registry
         from ceph_tpu.osd import ec_util
         from ceph_tpu.osd.ec_dispatch import ECDispatcher
-        from ceph_tpu.utils import native
-
-        # force the jax batching lane: the native C engine takes the
-        # per-op direct lane and never batches (by design)
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
 
         async def main():
             codec = registry.instance().factory(
@@ -474,13 +467,10 @@ class TestECDispatchClassLanes:
 
         run(main())
 
-    def test_background_stripes_pace_through_scheduler(self, monkeypatch):
+    def test_background_stripes_pace_through_scheduler(self):
         from ceph_tpu.models import registry
         from ceph_tpu.osd import ec_util
         from ceph_tpu.osd.ec_dispatch import ECDispatcher
-        from ceph_tpu.utils import native
-
-        monkeypatch.setattr(native, "host_engine_active", lambda: False)
 
         async def main():
             sched = OpScheduler(

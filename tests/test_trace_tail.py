@@ -569,21 +569,20 @@ class TestAccelReplayKept:
                 ) > 0
 
                 # wedge the accelerator's serving path (the
-                # make_pjrt_c_api_client stall; _run_direct is the
-                # choke point the native-direct lane this CPU host
-                # serves from rides too), stream a storm INTO the
-                # wedge, and SIGKILL once an OSD shows a remote batch
-                # in flight — the connection dies under a pending
-                # RPC, the canonical mid-batch crash (a kill between
-                # batches just reroutes: the router marks the accel
-                # unreachable before the next launch ever leaves)
-                orig_direct = acc.dispatch._run_direct
+                # make_pjrt_c_api_client stall; _launch is the choke
+                # point every batch it serves rides), stream a storm
+                # INTO the wedge, and SIGKILL once an OSD shows a
+                # remote batch in flight — the connection dies under a
+                # pending RPC, the canonical mid-batch crash (a kill
+                # between batches just reroutes: the router marks the
+                # accel unreachable before the next launch ever leaves)
+                orig_launch = acc.dispatch._launch
 
                 async def wedged(*a, **kw):
                     await asyncio.sleep(2.0)
-                    return await orig_direct(*a, **kw)
+                    return await orig_launch(*a, **kw)
 
-                acc.dispatch._run_direct = wedged
+                acc.dispatch._launch = wedged
                 stask = asyncio.ensure_future(storm(1))
 
                 def remote_pending():

@@ -11,8 +11,7 @@ Pins the PR-6 contract end to end:
   loop on unaligned offsets and short tails, and striped round trips
   stay bit-exact through a real cluster;
 - EC encode/decode through views equals the bytes path (and the
-  hardware crc32c equals the software tables, and the parallel native
-  stripes encode equals the serial one);
+  hardware crc32c equals the software tables);
 - the ``data_path.copied_bytes`` budget: a full write+read round trip
   instruments at most 1x the payload per direction.
 """
@@ -377,7 +376,7 @@ class TestECViews:
         assert bytes(got) == want
 
 
-# -- native engine: hw crc + parallel stripes --------------------------------
+# -- native engine: hw crc ----------------------------------------------------
 
 
 class TestNativeFastPaths:
@@ -400,20 +399,6 @@ class TestNativeFastPaths:
                     ctypes.c_uint32(seed & 0xFFFFFFFF), ptr, n
                 ))
                 assert hw == tab, (n, seed)
-
-    def test_parallel_stripe_encode_bit_identical(self, monkeypatch):
-        if not native.host_engine_active():
-            pytest.skip("native engine unavailable")
-        matrix = native.rs_vandermonde_matrix(6, 2, 8)
-        rng = np.random.default_rng(9)
-        S, cs, k = 64, 64 * 8, 6
-        buf = rng.integers(0, 256, size=(S * k * cs,), dtype=np.uint8)
-        monkeypatch.setenv("CEPH_TPU_NATIVE_WORKERS", "1")
-        ref = native.encode_stripes(matrix, buf, S, cs)
-        monkeypatch.setenv("CEPH_TPU_NATIVE_WORKERS", "3")
-        monkeypatch.setattr(native, "_PAR_MIN_BYTES", 1)  # force split
-        par = native.encode_stripes(matrix, buf, S, cs)
-        assert np.array_equal(ref, par)
 
 
 # -- the copy budget, end to end ---------------------------------------------
